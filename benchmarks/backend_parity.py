@@ -141,4 +141,7 @@ def run_paged(scale: float = 1.0, page: int = 8):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     run()

@@ -82,6 +82,10 @@ def run(scale: float = 1.0, backend: str = "reference"):
 if __name__ == "__main__":
     import argparse
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=1.0)
     ap.add_argument("--backend", default="reference",

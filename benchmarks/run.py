@@ -22,6 +22,10 @@ def main() -> None:
     ap.add_argument("--only", nargs="*", default=None)
     args, _ = ap.parse_known_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     from benchmarks import (
         backend_parity,
         conversion_overhead,

@@ -541,6 +541,9 @@ def run(scale: float = 1.0, argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     # standalone (CI) gates; the benchmarks.run harness only reports.
     # slot-steps are deterministic — that comparison is hard.  wall clock
     # on a shared runner is not, so the paired-median ratio only fails on a

@@ -85,7 +85,7 @@ class ModelConfig:
     # serving-engine paged-decode execution path (resolve_backend name):
     # "reference" reads pages through the jnp gather->attend oracle;
     # "pallas" streams pages through the fused paged-attention / paged-COW
-    # kernels (compiled on TPU, interpret mode elsewhere).  Part of the
+    # kernels (interpret mode on the CPU platform only).  Part of the
     # frozen config on purpose: every jitted step cache is keyed by it.
     decode_backend: str = "reference"
 

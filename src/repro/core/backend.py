@@ -12,8 +12,8 @@ implementations:
 * ``"pallas"`` — the Pallas kernels from :mod:`repro.kernels`: blocked GEMM,
   blocked softmax/layernorm, the fused GEMM+bias+GELU feed-forward, and the
   fused attention (scores -> softmax -> @V without materializing scores in
-  HBM).  On TPU these compile natively; elsewhere they run with
-  ``interpret=True`` so CPU CI exercises the identical BlockSpecs/grids.
+  HBM).  They compile on every platform but the CPU, where they run with
+  ``interpret=True`` so the tests exercise the identical BlockSpecs/grids.
 
 Layout-neutral element-wise ops (add, bias, scale, map) are shared: they are
 the paper's "Activation" category — no data movement depends on arrangement,
@@ -32,7 +32,7 @@ engine selects per :attr:`ModelConfig.decode_backend` via
 Select a backend by name or instance::
 
     from repro.core import backend as B
-    be = B.resolve_backend("pallas")           # interpret=auto (CPU -> True)
+    be = B.resolve_backend("pallas")           # interpret on CPU only
     be = B.resolve_backend("pallas", interpret=True)
     be = B.resolve_backend(MyCustomBackend())
 """
@@ -43,10 +43,6 @@ from typing import Callable, Dict, Optional, Protocol, Union, runtime_checkable
 
 import jax
 
-try:  # moved out of experimental in newer jax
-    from jax.shard_map import shard_map
-except ImportError:  # pragma: no cover - version dependent
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as _P
 
 from repro.core import blockwise as bw
@@ -181,11 +177,18 @@ class ReferenceBackend(_ElementwiseMixin):
         return attn.paged_copy_page(pools, src, dst)
 
 
+def _interpret_default() -> bool:
+    """Interpret Pallas kernels on the CPU platform only."""
+    return jax.default_backend() == "cpu"
+
+
 class PallasBackend(_ElementwiseMixin):
     """The Pallas BWMA kernels — the execution path the paper describes.
 
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere
-    (bit-accurate, runs in CPU CI).
+    ``interpret=None`` auto-selects: the interpreter on the CPU platform
+    (bit-accurate, the test path), compiled kernels everywhere else — a
+    platform that cannot compile them fails instead of silently running
+    interpreted XLA in place of the kernel.
     """
 
     name = "pallas"
@@ -221,7 +224,7 @@ class PallasBackend(_ElementwiseMixin):
     @property
     def interpret(self) -> bool:
         if self._interpret is None:
-            return jax.default_backend() != "tpu"
+            return _interpret_default()
         return self._interpret
 
     def matmul(self, a: Blocked, b: Blocked) -> Blocked:
@@ -268,10 +271,10 @@ class PallasBackend(_ElementwiseMixin):
         if pol is not None:
             tp = pol.tp_axis
             head = _P(None, None, tp, None)
-            return shard_map(
+            return jax.shard_map(
                 self._paged_attention_decode, mesh=pol.mesh,
                 in_specs=(head, head, head, _P(), _P()),
-                out_specs=head, check_rep=False,
+                out_specs=head, check_vma=False,
             )(q, k_pages, v_pages, page_table, seq_pos)
         return self._paged_attention_decode(
             q, k_pages, v_pages, page_table, seq_pos
@@ -285,13 +288,13 @@ class PallasBackend(_ElementwiseMixin):
             # latent pages carry no head axis: they stay replicated and each
             # device attends its own query heads against the full pools
             head = _P(None, None, pol.tp_axis, None)
-            return shard_map(
+            return jax.shard_map(
                 functools.partial(
                     self._mla_paged_attention_decode, scale=scale
                 ),
                 mesh=pol.mesh,
                 in_specs=(head, head, _P(), _P(), _P(), _P()),
-                out_specs=head, check_rep=False,
+                out_specs=head, check_vma=False,
             )(q_lat, q_rope, ckv_pages, krope_pages, page_table, seq_pos)
         return self._mla_paged_attention_decode(
             q_lat, q_rope, ckv_pages, krope_pages, page_table, seq_pos,
@@ -307,10 +310,10 @@ class PallasBackend(_ElementwiseMixin):
                    if pool.ndim == 5 else None)
             if pol is not None:
                 spec = _P(None, None, None, pol.tp_axis, None)
-                out[name] = shard_map(
+                out[name] = jax.shard_map(
                     self._paged_copy, mesh=pol.mesh,
                     in_specs=(spec, _P(), _P()),
-                    out_specs=spec, check_rep=False,
+                    out_specs=spec, check_vma=False,
                 )(pool, src, dst)
             else:
                 out[name] = self._paged_copy(pool, src, dst)
@@ -356,9 +359,8 @@ def resolve_backend(
         if takes_interpret:
             # normalize auto (None) to its resolved value so the auto and
             # explicit spellings share one instance (and one jit cache)
-            resolved = interpret if interpret is not None else (
-                jax.default_backend() != "tpu"
-            )
+            resolved = (interpret if interpret is not None
+                        else _interpret_default())
             key = (spec, resolved)
             kw = {"interpret": resolved}
         else:

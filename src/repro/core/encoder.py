@@ -12,9 +12,9 @@ Two functionally-identical paths are provided:
   execution :class:`~repro.core.backend.Backend`:
 
   - ``backend="reference"`` — the pure-jnp blockwise operators,
-  - ``backend="pallas"`` — the Pallas BWMA kernels (compiled on TPU,
-    ``interpret=True`` elsewhere), including the fused attention and the
-    fused GEMM+bias+GELU feed-forward.
+  - ``backend="pallas"`` — the Pallas BWMA kernels (``interpret=True``
+    on the CPU platform, compiled elsewhere), including the fused
+    attention and the fused GEMM+bias+GELU feed-forward.
 
 All paths must agree to float tolerance (tested); the *performance*
 difference is what ``repro.core.memmodel`` and the Pallas kernels quantify.

@@ -15,7 +15,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
 import numpy as np
@@ -26,22 +26,9 @@ from jax.sharding import PartitionSpec as P
 def abstract_mesh(
     axis_sizes: Sequence[int], axis_names: Sequence[str]
 ) -> AbstractMesh:
-    """Version-portable :class:`jax.sharding.AbstractMesh` construction.
-
-    The constructor signature has changed across jax releases: older
-    versions take ``AbstractMesh(shape_tuple)`` with ``((name, size), ...)``
-    pairs, newer ones take ``AbstractMesh(axis_sizes, axis_names)``.  All
-    mesh-shape validation (tests, launch dry-runs) should build meshes here
-    so a jax bump touches one place.
-    """
-    sizes: Tuple[int, ...] = tuple(int(s) for s in axis_sizes)
-    names: Tuple[str, ...] = tuple(axis_names)
-    if len(sizes) != len(names):
-        raise ValueError(f"{len(sizes)} axis sizes for {len(names)} names")
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
+    """A :class:`jax.sharding.AbstractMesh` for mesh-shape validation
+    (tests, launch dry-runs) that needs no devices."""
+    return AbstractMesh(tuple(int(s) for s in axis_sizes), tuple(axis_names))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,12 +1,12 @@
 """Public convenience wrappers around the Pallas kernels.
 
-Dispatch policy lives in :class:`repro.core.backend.PallasBackend` (compiled
-natively on TPU, ``interpret=True`` elsewhere); these wrappers delegate to
-the shared, memoized instance from :func:`resolve_backend` so every caller
-hits the same per-shape jit cache.  They also adapt between the logical
-(2-D) world and the blocked (BWMA) world using :mod:`repro.core.layout`,
-carrying the accelerator block size as the layout quantum (the paper's
-'governed by the kernel size').
+Dispatch policy lives in :class:`repro.core.backend.PallasBackend`
+(interpret mode on the CPU platform, compiled everywhere else); these
+wrappers delegate to the shared, memoized instance from
+:func:`resolve_backend` so every caller hits the same per-shape jit cache.
+They also adapt between the logical (2-D) world and the blocked (BWMA)
+world using :mod:`repro.core.layout`, carrying the accelerator block size
+as the layout quantum (the paper's 'governed by the kernel size').
 
 Dtype contract: the element-wise-shaped ops (softmax/layernorm/attention)
 preserve the input dtype, matching the backend convention.  The GEMM-shaped

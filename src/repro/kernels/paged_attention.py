@@ -37,10 +37,11 @@ Three kernels:
   and ``input_output_aliases`` keeps the pool update in place (the donating
   COW jit's aliasing survives, see tests).
 
-All three run compiled on TPU and under ``interpret=True`` elsewhere (CPU
-CI exercises the identical grids/BlockSpecs).  They are plain traceable
-functions — no inner ``jax.jit`` — so the engine's already-jitted decode /
-COW steps inline them without nested-pjit donation hazards.
+All three compile for the TPU and run under ``interpret=True`` on the CPU
+platform (the tests exercise the identical grids/BlockSpecs).  They are
+plain traceable functions — no inner ``jax.jit`` — so the engine's
+already-jitted decode / COW steps inline them without nested-pjit donation
+hazards.
 """
 from __future__ import annotations
 
@@ -263,33 +264,58 @@ def _copy_kernel(src_ref, dst_ref, x_ref, o_ref):
     o_ref[...] = x_ref[...]
 
 
+def _tiled_order(shape) -> tuple:
+    """Axis order of a stacked pool whose two minor dims fill TPU tiles.
+
+    Within one page the minor dim should be a multiple of the 128-lane
+    width, and the second-minor one a multiple or a divisor of the 8-row
+    sublane count.  XLA's TPU layout assignment stores a pool in such an
+    order (minicpm-2b's ``(L, P, 128, 36, 64)`` pool sits page-axis-minor
+    in HBM), so the transpose into it is a bitcast there, and a kernel
+    reading the transposed view needs no pool-sized relayout copy.
+    """
+    axes = list(range(2, len(shape)))
+    minor = next((a for a in reversed(axes) if shape[a] % 128 == 0),
+                 axes[-1])
+    axes.remove(minor)
+    if axes:
+        second = next((a for a in reversed(axes)
+                       if shape[a] % 8 == 0 or 8 % shape[a] == 0), axes[-1])
+        axes.remove(second)
+        axes.append(second)
+    return (0, 1, *axes, minor)
+
+
 def paged_copy(pool, src, dst, *, interpret: bool = False):
     """Copy physical page ``src`` -> ``dst`` in one stacked page pool.
 
     ``pool``: (L, num_pages, page, ...) — any paged leaf (dense K/V or MLA
-    latent; the page axis is axis 1 after layer stacking).  The grid is one
-    step per stacked layer; scalar-prefetched page ids drive the input and
-    output index maps, and ``input_output_aliases`` makes every non-``dst``
-    page a true no-op (the engine's donating COW jit keeps its in-place
-    aliasing — the pool is never duplicated).  Bit-exact by construction.
+    latent; the page axis is axis 1 after layer stacking).  The kernel
+    reads the pool through its tile-aligned view (:func:`_tiled_order`),
+    one whole page block per grid step, one step per stacked layer;
+    scalar-prefetched page ids drive the input and output index maps, and
+    ``input_output_aliases`` makes every non-``dst`` page a true no-op (the
+    engine's donating COW jit keeps its in-place aliasing — the pool is
+    never duplicated).  Bit-exact by construction.
     """
-    lead = pool.shape[0]
-    flat = pool.reshape(lead, pool.shape[1], -1)
-    f = flat.shape[-1]
+    order = _tiled_order(pool.shape)
+    view = jnp.transpose(pool, order)
+    block = (None, None) + view.shape[2:]
+    rest = (0,) * (view.ndim - 2)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # src page id, dst page id (each shape (1,))
-        grid=(lead,),
-        in_specs=[pl.BlockSpec((1, 1, f), lambda l, s, d: (l, s[0], 0))],
-        out_specs=pl.BlockSpec((1, 1, f), lambda l, s, d: (l, d[0], 0)),
+        grid=(view.shape[0],),
+        in_specs=[pl.BlockSpec(block, lambda l, s, d: (l, s[0], *rest))],
+        out_specs=pl.BlockSpec(block, lambda l, s, d: (l, d[0], *rest)),
     )
     out = pl.pallas_call(
         _copy_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(flat.shape, flat.dtype),
+        out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype),
         # operand indices include the scalar-prefetch args: aliased operand
         # 2 is the pool itself
         input_output_aliases={2: 0},
         interpret=interpret,
     )(jnp.asarray(src, jnp.int32).reshape(1),
-      jnp.asarray(dst, jnp.int32).reshape(1), flat)
-    return out.reshape(pool.shape)
+      jnp.asarray(dst, jnp.int32).reshape(1), view)
+    return jnp.transpose(out, [order.index(a) for a in range(out.ndim)])

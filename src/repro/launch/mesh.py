@@ -7,18 +7,25 @@ Multi-pod: 2 pods x 256 = 512 chips with a leading "pod" axis (DCN).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """A device mesh whose axes GSPMD shards automatically (jax's default
+    is explicit sharding-in-types, which the sharding rules here predate)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_local_mesh():
     """Whatever devices exist (tests / examples on CPU): (data=1, model=n)."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return _mesh((1, n), ("data", "model"))
 
 
 def make_serve_mesh(spec: str):
@@ -43,4 +50,4 @@ def make_serve_mesh(spec: str):
             f"simulate with XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{d * m} (must be set before jax initializes)"
         )
-    return jax.make_mesh((d, m), ("data", "model"))
+    return _mesh((d, m), ("data", "model"))

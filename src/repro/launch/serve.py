@@ -16,7 +16,7 @@ through immutable per-slot cross rows + paged decoder self-attention:
       --num-requests 6 --max-seqs 2 --prompt-len 8 --max-new 12
 
 ``--backend pallas`` serves the paged decode + COW path through the fused
-Pallas kernels (compiled on TPU, interpret mode elsewhere):
+Pallas kernels (interpret mode on the CPU platform, compiled elsewhere):
 
   PYTHONPATH=src python -m repro.launch.serve --arch minicpm-2b --smoke \
       --num-requests 6 --max-seqs 2 --backend pallas
@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 import repro.configs as C
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import adapters as A
 from repro.models import model as M
 from repro.serve import (
@@ -210,8 +211,8 @@ def main():
                     choices=("reference", "pallas"),
                     help="paged-decode path for the continuous engine: the "
                          "jnp gather oracle or the fused paged-attention / "
-                         "COW kernels (compiled on TPU, interpret mode "
-                         "elsewhere; families without paged decode fall "
+                         "COW kernels (interpret mode on the CPU platform, "
+                         "compiled elsewhere; families without paged decode fall "
                          "back to their reference path)")
     ap.add_argument("--mesh", default="",
                     help="DxM device mesh (e.g. 1x2): serve tensor-parallel "
@@ -243,6 +244,7 @@ def main():
                          "frees at the step that introduces them)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.prefill_chunks_per_step is not None:
         # one-shot per process; the Engine would also warn, but the flag

@@ -51,7 +51,8 @@ def dense(cfg: ModelConfig, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     * ``bwma`` — route through the Pallas blocked-GEMM kernel: weights and
       activations move HBM->VMEM as contiguous accelerator-sized blocks
       (paper Fig. 4d).  On CPU this runs in interpret mode (small scale);
-    * ``rwma`` — the row-major tiled Pallas kernel (the paper's baseline).
+    * ``rwma`` — the row-major tiled Pallas kernel (the paper's baseline);
+      shapes the block does not divide are refused, not run as ``x @ w``.
     """
     if cfg.gemm_backend == "xla" or w.ndim != 2:
         return x @ w
@@ -76,10 +77,12 @@ def dense(cfg: ModelConfig, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
         m, k = x2.shape
         n = w.shape[1]
         if m % blk or k % blk or n % blk:
-            out = x2 @ w  # row-major kernel needs divisible shapes
-        else:
-            # memoized jit wrapper sharing the backend's dispatch policy
-            out = kops.matmul_rwma(x2, w, bm=blk, bk=blk, bn=blk)
+            raise ValueError(
+                f"gemm_backend='rwma' needs every matmul dim divisible by "
+                f"the block {blk}, got ({m}, {k}) @ ({k}, {n})"
+            )
+        # memoized jit wrapper sharing the backend's dispatch policy
+        out = kops.matmul_rwma(x2, w, bm=blk, bk=blk, bn=blk)
     return out.astype(x.dtype).reshape(*lead, w.shape[1])
 
 

@@ -667,9 +667,16 @@ class PagedKVCache:
 
         The ``jnp.asarray`` here is a host->device upload (not a sync) and
         runs only on steps where a table entry actually changed; steady-state
-        decode reuses ``_table_dev`` without touching the host array."""
+        decode reuses ``_table_dev`` without touching the host array.
+
+        It uploads a snapshot, never ``_table`` itself: the CPU backend may
+        alias an aligned host array instead of copying it, and a TPU upload
+        may still be reading it, so the in-place edits of a later growth,
+        COW or release would reach a step that was dispatched earlier and
+        has not run yet (a finished request's last token read through its
+        already-released, all-null table row)."""
         if self._table_dev is None:
-            self._table_dev = jnp.asarray(self._table)
+            self._table_dev = jnp.asarray(self._table.copy())
         return self._table_dev
 
     # -- prefill install ----------------------------------------------------

@@ -46,12 +46,24 @@ def test_resolve_backend():
     assert resolve_backend(pb) is pb
     # auto (None) and the explicitly-resolved value share one instance/cache
     assert resolve_backend("pallas") is resolve_backend(
-        "pallas", interpret=jax.default_backend() != "tpu"
+        "pallas", interpret=jax.default_backend() == "cpu"
     )
     with pytest.raises(ValueError):
         resolve_backend("no-such-backend")
     with pytest.raises(ValueError):
         resolve_backend("reference", interpret=True)  # not a silent no-op
+
+
+@pytest.mark.parametrize("platform,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", False),
+])
+def test_interpret_only_on_cpu(monkeypatch, platform, interpret):
+    """Interpret mode is the CPU test path only: any other platform gets
+    compiled kernels (or a compile error), never interpreted XLA posing as
+    the kernel path."""
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert PallasBackend().interpret is interpret
+    assert PallasBackend(interpret=True).interpret
 
 
 def test_pallas_matches_reference_ragged():

@@ -128,7 +128,7 @@ def test_gather_stats_sees_nested_pjit_gather():
 # --------------------------------------------------------------------------
 
 def test_rpj103_seeded_f64_upcast():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         spec = StepSpec(
             name="upcast", fn=lambda x: x.astype(jnp.float64) * 2.0,
             args=(_sds((16,), jnp.float32),),

@@ -212,3 +212,17 @@ def test_gemm_backend_bwma_matches_xla():
         lb, _, _ = M.forward_train(cfgb, params, batch, remat=False)
         np.testing.assert_allclose(np.asarray(lb), np.asarray(lx),
                                    rtol=2e-4, atol=2e-4)
+
+
+def test_rwma_refuses_indivisible_shapes():
+    """The row-major kernel path never falls back to a plain matmul."""
+    import dataclasses
+    cfg = dataclasses.replace(
+        C.get_config("minicpm-2b", smoke=True, dtype=jnp.float32,
+                     block=16),
+        gemm_backend="rwma",
+    )
+    from repro.models.common import dense
+    x = jnp.ones((3, 24), jnp.float32)  # 24 is not a multiple of 16
+    with pytest.raises(ValueError, match="divisible"):
+        dense(cfg, x, jnp.ones((24, 32), jnp.float32))

@@ -113,6 +113,22 @@ def test_kvcache_admission_accounting():
     assert int(np.asarray(kv.page_table()).max()) == 0
 
 
+def test_page_table_upload_is_a_snapshot():
+    """A device page table keeps the rows it was uploaded with: the host's
+    later in-place edits (growth, release) must not reach a step already
+    dispatched with it.  The CPU backend aliases only some (aligned) host
+    arrays, so the check repeats the cycle until aliasing would show."""
+    kv = PagedKVCache(_paged_cfg(block=4),
+                      PagedCacheConfig(max_seqs=2, max_len=16, num_pages=6))
+    for _ in range(20):
+        kv.admit(0, 10)
+        dev = kv.page_table()
+        before = np.asarray(dev).copy()
+        assert kv.ensure_capacity(0, 12)
+        kv.release(0)
+        np.testing.assert_array_equal(np.asarray(dev), before)
+
+
 def test_kvcache_rejects_unservable_request():
     cfg = _paged_cfg(block=4)
     kv = PagedKVCache(cfg, PagedCacheConfig(max_seqs=1, max_len=8, num_pages=3))
