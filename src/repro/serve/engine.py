@@ -877,33 +877,24 @@ def make_requests(
     mean_interarrival: float = 0.0,
     vary_lengths: bool = True,
     seed: int = 0,
-    min_prompt_len: int = 0,
-    min_new: int = 2,
 ) -> List[dict]:
     """Deterministic 'Poisson-ish' smoke workload: exponential inter-arrival
     gaps (in decode-step units) and per-request generation lengths, all from
-    one seeded generator.  Returns plain dicts so both engines can consume.
-
-    Generation lengths spread over ``[min_new, max_new]`` (``vary_lengths``);
-    ``min_prompt_len > 0`` spreads prompt lengths over ``[min_prompt_len,
-    prompt_len]`` too, otherwise every prompt has ``prompt_len`` tokens."""
+    one seeded generator.  Returns plain dicts so both engines can consume."""
     rng = np.random.default_rng(seed)
     reqs, step = [], 0
     for i in range(num_requests):
         if i and mean_interarrival > 0:
             step += int(rng.exponential(mean_interarrival))
-        # generation lengths spread over [min_new, max_new]: realistic
-        # serving traffic is length-heterogeneous, which is precisely what
-        # lockstep waves pay for and slot re-fill does not
+        # generation lengths spread over [2, max_new]: realistic serving
+        # traffic is length-heterogeneous, which is precisely what lockstep
+        # waves pay for and slot re-fill does not
         n_new = (
-            int(rng.integers(min_new, max_new + 1)) if vary_lengths
-            else max_new
+            int(rng.integers(2, max_new + 1)) if vary_lengths else max_new
         )
-        n_prompt = (int(rng.integers(min_prompt_len, prompt_len + 1))
-                    if min_prompt_len else prompt_len)
         reqs.append({
             "rid": i,
-            "prompt": rng.integers(0, vocab_size, size=(n_prompt,)).astype(np.int32),
+            "prompt": rng.integers(0, vocab_size, size=(prompt_len,)).astype(np.int32),
             "max_new_tokens": n_new,
             "arrival_step": step,
         })

@@ -80,9 +80,11 @@ def test_mla_paged_attention_decode_deepseek_v3(one_chip):
 
 @pytest.mark.parametrize("pool", [
     (40, NUM_PAGES, PAGE, 36, 64),  # minicpm-2b K or V pages
+    (40, NUM_PAGES, PAGE, 9, 64),   # minicpm-2b per device at TP=4
     (61, NUM_PAGES, PAGE, 512),     # deepseek-v3 latent pages
     (61, NUM_PAGES, PAGE, 64),      # deepseek-v3 rope-key pages
-], ids=["minicpm-2b", "deepseek-v3-latent", "deepseek-v3-rope"])
+], ids=["minicpm-2b", "minicpm-2b-tp4-shard", "deepseek-v3-latent",
+        "deepseek-v3-rope"])
 def test_paged_copy_stacked_pools(one_chip, pool):
     """The COW copy compiles in place: the donated pool is aliased and no
     pool-sized relayout buffer appears next to it."""
