@@ -154,6 +154,7 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, dh), q.dtype),
         interpret=interpret,
+        name="paged_attention_decode",
     )(page_table.astype(jnp.int32), seq_pos.astype(jnp.int32),
       q[:, 0], k_pages, v_pages)
     return out[:, None]
@@ -251,6 +252,7 @@ def mla_paged_attention_decode(q_lat, q_rope, ckv_pages, krope_pages,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, r), ckv_pages.dtype),
         interpret=interpret,
+        name="mla_paged_attention_decode",
     )(page_table.astype(jnp.int32), seq_pos.astype(jnp.int32),
       q_lat[:, 0], q_rope[:, 0], ckv_pages, krope_pages)
     return out[:, None]
@@ -316,6 +318,7 @@ def paged_copy(pool, src, dst, *, interpret: bool = False):
         # 2 is the pool itself
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="paged_copy",
     )(jnp.asarray(src, jnp.int32).reshape(1),
       jnp.asarray(dst, jnp.int32).reshape(1), view)
     return jnp.transpose(out, [order.index(a) for a in range(out.ndim)])

@@ -226,9 +226,8 @@ def main():
                          "stateful families disable it automatically")
     ap.add_argument("--obs", action="store_true",
                     help="deep observability: audit-backed pool gauges every "
-                         "step + jax.profiler.TraceAnnotation around the "
-                         "jitted decode/chunk steps (spans and counters are "
-                         "always recorded; this only deepens collection)")
+                         "step (spans, counters and the profiler step spans "
+                         "are always recorded; this only adds the audit)")
     ap.add_argument("--json-report", default="",
                     help="write the latency/prefix-cache report (the table "
                          "above, machine-readable, plus the full metrics "

@@ -154,17 +154,26 @@ def chunk_shape_set(cfg: ModelConfig, chunk_size: int) -> tuple:
     return tuple(range(1, chunk_size + 1))
 
 
+def _bound(fn, cfg: ModelConfig):
+    """``functools.partial(fn, cfg)`` named after ``fn``: jit names its
+    program, and the profiler its runs, ``jit_<name>`` (a bare partial
+    lowers as ``jit__unknown``), so a trace tells the steps apart."""
+    step = functools.partial(fn, cfg)
+    step.__name__ = step.__qualname__ = fn.__name__
+    return step
+
+
 # jitted step functions are memoized per (hashable, frozen) ModelConfig so
 # every engine instance — and repeated benchmark constructions — share one
 # compile cache; the mesh path builds its own closures under the mesh context
 @functools.lru_cache(maxsize=None)
 def _prefill_fn(cfg: ModelConfig):
-    return jax.jit(functools.partial(M.prefill, cfg))
+    return jax.jit(_bound(M.prefill, cfg))
 
 
 @functools.lru_cache(maxsize=None)
 def _decode_fn(cfg: ModelConfig):
-    return jax.jit(functools.partial(M.decode_step, cfg))
+    return jax.jit(_bound(M.decode_step, cfg))
 
 
 def _paged_step(cfg: ModelConfig, params, caches, tokens, seq_pos, page_table,
@@ -192,17 +201,12 @@ def _donate_caches() -> tuple:
 
 @functools.lru_cache(maxsize=None)
 def _decode_paged_fn(cfg: ModelConfig):
-    return jax.jit(
-        functools.partial(_paged_step, cfg), donate_argnums=_donate_caches()
-    )
+    return jax.jit(_bound(_paged_step, cfg), donate_argnums=_donate_caches())
 
 
 @functools.lru_cache(maxsize=None)
 def _prefill_chunk_fn(cfg: ModelConfig):
-    return jax.jit(
-        functools.partial(M.prefill_chunk, cfg),
-        donate_argnums=_donate_caches(),
-    )
+    return jax.jit(_bound(M.prefill_chunk, cfg), donate_argnums=_donate_caches())
 
 
 def jitted_step_fns(cfg: ModelConfig) -> Dict[str, tuple]:
@@ -224,10 +228,8 @@ def jitted_step_fns(cfg: ModelConfig) -> Dict[str, tuple]:
     from repro.serve import kvcache as KV
 
     return {
-        "decode_step": (functools.partial(_paged_step, cfg), _donate_caches()),
-        "prefill_chunk": (
-            functools.partial(M.prefill_chunk, cfg), _donate_caches()
-        ),
+        "decode_step": (_bound(_paged_step, cfg), _donate_caches()),
+        "prefill_chunk": (_bound(M.prefill_chunk, cfg), _donate_caches()),
         "cow_copy": (KV.cow_step(cfg), KV.POOL_DONATE),
         "install": (KV.install_step(cfg), KV.POOL_DONATE),
     }
@@ -385,12 +387,9 @@ class EngineConfig:
     # debugging aid: cross-checks allocator refcounts against slot page
     # tables and the prefix index — free + index-pinned + slot-held == total)
     debug_audit: bool = False
-    # deep observability: spans/counters/cheap gauges are always on (host
-    # int bookkeeping at scheduling events — cannot change outputs); this
-    # additionally runs the pool audit every step for the
-    # free/index_pinned/slot_held gauge split and wraps the jitted
-    # decode/chunk dispatches in jax.profiler.TraceAnnotation so device
-    # traces line up with the host spans
+    # deep observability: spans, counters and cheap gauges are always on
+    # (host bookkeeping that cannot change outputs); this additionally runs
+    # the pool audit every step for the index_pinned/slot_held gauge split
     obs: bool = False
 
 
@@ -480,10 +479,10 @@ class Engine:
             )
             self.params = jax.device_put(params, param_sh)
             self._prefill = jax.jit(
-                AX.traced_under(mesh, functools.partial(M.prefill, cfg))
+                AX.traced_under(mesh, _bound(M.prefill, cfg))
             )
             self._chunk_fn = jax.jit(
-                AX.traced_under(mesh, functools.partial(M.prefill_chunk, cfg)),
+                AX.traced_under(mesh, _bound(M.prefill_chunk, cfg)),
                 in_shardings=(
                     param_sh, pool_sh, rep, rep, rep, rep, rep, rep, rep
                 ),
@@ -491,7 +490,7 @@ class Engine:
                 donate_argnums=_donate_caches(),
             )
             self._decode = jax.jit(
-                AX.traced_under(mesh, functools.partial(_paged_step, cfg)),
+                AX.traced_under(mesh, _bound(_paged_step, cfg)),
                 in_shardings=(param_sh, pool_sh, rep, rep, rep, rep),
                 out_shardings=(rep, rep, pool_sh),
                 donate_argnums=_donate_caches(),
@@ -552,20 +551,19 @@ class Engine:
         if self.ec.temperature <= 0:
             # callers that can defer use the on-device greedy feedback path,
             # not _sample — this sync only runs at scheduling events
-            return int(jnp.argmax(lf))  # repro: noqa RPR002 -- sanctioned sync
-        # per-request, per-position key: independent of scheduling order
-        key = jax.random.fold_in(
-            jax.random.fold_in(jax.random.PRNGKey(self.ec.seed), req.rid),
-            len(req.out_tokens),
-        )
-        # host sampling needs the value now; the greedy path never comes here
-        return int(  # repro: noqa RPR002 -- sanctioned sync
-            jax.random.categorical(key, lf / self.ec.temperature)
-        )
+            tok = jnp.argmax(lf)
+        else:
+            # per-request, per-position key: independent of scheduling order
+            key = jax.random.fold_in(
+                jax.random.fold_in(jax.random.PRNGKey(self.ec.seed), req.rid),
+                len(req.out_tokens),
+            )
+            tok = jax.random.categorical(key, lf / self.ec.temperature)
+        # the host needs the value now: scheduling reads every sampled token
+        with self.obs.sync():
+            return int(tok)  # repro: noqa RPR002 -- sanctioned sync
 
     def _append_token(self, slot: int, req: Request, tok: int) -> None:
-        # the first-token milestone is recorded by obs.prefill_complete
-        # (callers fire it right before sampling the first token)
         req.out_tokens.append(tok)
         self._last_tok = self._last_tok.at[slot].set(tok)
         if req.done or (self.ec.eos_id is not None and tok == self.ec.eos_id):
@@ -578,16 +576,18 @@ class Engine:
         so this drains the async queue once instead of once per step."""
         if not self._pending:
             return
-        # THE deferred-sync point: the only place the greedy decode loop
-        # pays device->host, amortized over every step since the last flush
-        rows = np.stack(  # repro: noqa RPR002 -- sanctioned deferred sync
-            [np.asarray(g) for g, _ in self._pending]  # repro: noqa RPR002
-        )
-        for row, (_, running) in zip(rows, self._pending):
-            for slot, req in running:
-                req.out_tokens.append(int(row[slot]))  # repro: noqa RPR002 -- host ndarray
-                req.n_pending -= 1
-        self._pending.clear()
+        with self.obs.span("serve.flush"):
+            # THE deferred-sync point: the only place the greedy decode loop
+            # pays device->host, amortized over every step since the last flush
+            with self.obs.sync(len(self._pending)):
+                rows = np.stack(  # repro: noqa RPR002 -- sanctioned deferred sync
+                    [np.asarray(g) for g, _ in self._pending]  # repro: noqa RPR002
+                )
+            for row, (_, running) in zip(rows, self._pending):
+                for slot, req in running:
+                    req.out_tokens.append(int(row[slot]))  # repro: noqa RPR002 -- host ndarray
+                    req.n_pending -= 1
+            self._pending.clear()
 
     # -- prefill ------------------------------------------------------------
 
@@ -630,81 +630,87 @@ class Engine:
         Returns the number of real prompt tokens consumed (the admission
         budget's unit).
         """
-        prompt = req.effective_prompt
-        off = req.prefill_pos
-        n = min(self.chunk_size, len(prompt) - off)
-        # full chunks share ONE jit shape; the final ragged chunk draws from
-        # the small bucketed/exact shape set (bounded by the chunk size)
-        n_pad = self._last_chunk_len(n) if off + n >= len(prompt) else n
-        toks = np.zeros((1, n_pad), np.int32)
-        toks[0, :n] = prompt[off : off + n]
-        phys_tok, off_tok = self.kv.token_targets(slot, off, n_pad)
-        self.obs.chunk_begin(req, self.step_count, off, n)
-        with self.obs.device_span("prefill_chunk"):
+        with self.obs.span("serve.prefill"):
+            prompt = req.effective_prompt
+            off = req.prefill_pos
+            n = min(self.chunk_size, len(prompt) - off)
+            # full chunks share ONE jit shape; the final ragged chunk draws
+            # from the small bucketed/exact shape set (bounded by the chunk size)
+            n_pad = self._last_chunk_len(n) if off + n >= len(prompt) else n
+            toks = np.zeros((1, n_pad), np.int32)
+            toks[0, :n] = prompt[off : off + n]
+            phys_tok, off_tok = self.kv.token_targets(slot, off, n_pad)
+            self.obs.chunk_begin(req, self.step_count, off, n)
             logits, self.kv.data = self._chunk_fn(
                 self.params, self.kv.data, jnp.asarray(toks), jnp.int32(slot),
                 jnp.int32(off), phys_tok, off_tok, self.kv.table_row(slot),
                 jnp.int32(n - 1),
             )
-        req.prefill_pos += n
-        self.prefill_tokens += n
-        self.prefill_chunks += 1
-        self.obs.chunk_end(req, self.step_count)
-        # publish newly completed full pages: from here on, prompts sharing
-        # this prefix alias these pages instead of recomputing them
-        self.kv.commit_prefix(slot, prompt, req.prefill_pos)
+            req.prefill_pos += n
+            self.prefill_tokens += n
+            self.prefill_chunks += 1
+            self.obs.chunk_end(req, self.step_count)
+            # publish newly completed full pages: from here on, prompts
+            # sharing this prefix alias these pages instead of recomputing them
+            self.kv.commit_prefix(slot, prompt, req.prefill_pos)
         if not req.prefilling:  # final chunk: sample the first token
-            # close the prefill span / open decode BEFORE sampling: with
-            # max_new == 1 the sampled token finishes the request, and
-            # finish must close an already-open decode span
-            self.obs.prefill_complete(req, self.step_count)
-            self._append_token(slot, req, self._sample(logits[0, -1], req))
+            self._first_token(slot, req, logits[0, -1])
         return n
+
+    def _first_token(self, slot: int, req: Request, row_logits) -> None:  # repro: hot-loop
+        """Sample an admission's first token and stamp the milestone once
+        the token is on the host.  The prefill span closes and the decode
+        span opens before the token is appended: with max_new == 1 it
+        finishes the request, and finish must close an open decode span."""
+        with self.obs.span("serve.sample"):
+            tok = self._sample(row_logits, req)
+            self.obs.prefill_complete(req, self.step_count)
+            self._append_token(slot, req, tok)
 
     def _prefill_full(self, slot: int, req: Request) -> None:
         """One-shot prefill + jitted donating install (unchunked path)."""
-        prompt = req.effective_prompt
-        S = len(prompt)
-        extras = self._extras_batch(req)
-        if M.supports_padded_prefill(self.cfg):
-            # clamp to the per-slot capacity: positions past max_len can
-            # never be used, so padding beyond it would only waste compute
-            # and compile an oversized shape
-            Sp = min(bucket_tokens(S, self.kv.page_size), self.kv.max_len)
-            toks = np.zeros((1, Sp), np.int32)
-            toks[0, :S] = prompt
-            with self.obs.device_span("prefill_full"):
+        with self.obs.span("serve.prefill"):
+            prompt = req.effective_prompt
+            S = len(prompt)
+            extras = self._extras_batch(req)
+            if M.supports_padded_prefill(self.cfg):
+                # clamp to the per-slot capacity: positions past max_len can
+                # never be used, so padding beyond it would only waste
+                # compute and compile an oversized shape
+                Sp = min(bucket_tokens(S, self.kv.page_size), self.kv.max_len)
+                toks = np.zeros((1, Sp), np.int32)
+                toks[0, :S] = prompt
                 logits, caches = self._prefill(
                     self.params, {"tokens": jnp.asarray(toks), **extras},
                     jnp.int32(S - 1),
                 )
-        else:
-            with self.obs.device_span("prefill_full"):
+            else:
                 logits, caches = self._prefill(
                     self.params, {"tokens": jnp.asarray(prompt)[None], **extras}
                 )
-        self.kv.install_prefill(slot, caches)
-        req.prefill_pos = req.prefill_target
-        self.prefill_tokens += S
-        self.kv.commit_prefix(slot, prompt, S)
-        # span ordering as in _prefill_one_chunk: decode must be open
-        # before a max_new == 1 request finishes inside _append_token
-        self.obs.prefill_complete(req, self.step_count)
-        self._append_token(slot, req, self._sample(logits[0, -1], req))
+            self.kv.install_prefill(slot, caches)
+            req.prefill_pos = req.prefill_target
+            self.prefill_tokens += S
+            self.kv.commit_prefix(slot, prompt, S)
+        self._first_token(slot, req, logits[0, -1])
 
     # -- engine steps -------------------------------------------------------
 
     def _admit_and_prefill(self) -> None:  # repro: hot-loop
-        admitted = self.sched.admit(self.step_count)
+        with self.obs.span("serve.schedule"):
+            self.sched.poll_arrivals(self.step_count)
+            admitted = self.sched.admit(self.step_count)
+            if self.ec.chunked_prefill:
+                # request-level admission context (enc-dec encoder K/V)
+                # installs at admission, not on the first chunk: a
+                # shared-prefix admission may resume its chunking mid-prompt
+                # and never see offset 0
+                for slot, req in admitted:
+                    self._install_admission_context(slot, req)
         if not self.ec.chunked_prefill:
             for slot, req in admitted:
                 self._prefill_full(slot, req)
             return
-        # request-level admission context (enc-dec encoder K/V) installs at
-        # admission, not on the first chunk: a shared-prefix admission may
-        # resume its chunking mid-prompt and never see offset 0
-        for slot, req in admitted:
-            self._install_admission_context(slot, req)
         # token budget: oldest admission first (FIFO toward first token);
         # whatever is left after the budget waits for the next engine step,
         # with the decode batch stepping in between — a max-length prompt
@@ -720,27 +726,29 @@ class Engine:
                 break
 
     def _decode_once(self) -> None:  # repro: hot-loop
-        decoding = self.sched.decoding
-        deficit = sum(
-            self.kv.growth_deficit(slot, req.next_pos) for slot, req in decoding
-        ) if decoding else 0
-        # available_pages walks the prefix tree — consult it only when the
-        # free list alone cannot cover the round's growth
-        if deficit > self.kv.num_free_pages and deficit > self.kv.available_pages:
-            # the growth round below may preempt: victims must carry their
-            # full token history back to the queue, so sync first
-            self._flush_pending()
-        self.sched.grow_for_decode(self.step_count)
-        decoding = self.sched.decoding
-        self.obs.decode_batch(len(decoding))
-        if not decoding:
-            return
-        seq_pos = np.zeros((self.ec.max_seqs,), np.int32)  # idle slots -> 0
-        active = np.zeros((self.ec.max_seqs,), bool)  # idle/prefilling: False
-        for slot, req in decoding:
-            seq_pos[slot] = req.next_pos
-            active[slot] = True
-        with self.obs.device_span("decode_step"):
+        with self.obs.span("serve.decode"):
+            decoding = self.sched.decoding
+            deficit = sum(
+                self.kv.growth_deficit(slot, req.next_pos)
+                for slot, req in decoding
+            ) if decoding else 0
+            # available_pages walks the prefix tree — consult it only when
+            # the free list alone cannot cover the round's growth
+            if (deficit > self.kv.num_free_pages
+                    and deficit > self.kv.available_pages):
+                # the growth round below may preempt: victims must carry
+                # their full token history back to the queue, so sync first
+                self._flush_pending()
+            self.sched.grow_for_decode(self.step_count)
+            decoding = self.sched.decoding
+            self.obs.decode_batch(len(decoding))
+            if not decoding:
+                return
+            seq_pos = np.zeros((self.ec.max_seqs,), np.int32)  # idle slots -> 0
+            active = np.zeros((self.ec.max_seqs,), bool)  # idle/prefilling: False
+            for slot, req in decoding:
+                seq_pos[slot] = req.next_pos
+                active[slot] = True
             greedy, logits, self.kv.data = self._decode(
                 self.params, self.kv.data, self._last_tok[:, None],
                 jnp.asarray(seq_pos), self.kv.page_table(), jnp.asarray(active),
@@ -748,8 +756,11 @@ class Engine:
         self.decode_steps += 1
         if self.ec.temperature > 0:
             # host sampling needs the logits now — no deferral on this path
-            for slot, req in decoding:
-                self._append_token(slot, req, self._sample(logits[slot, -1], req))
+            with self.obs.span("serve.sample"):
+                for slot, req in decoding:
+                    self._append_token(
+                        slot, req, self._sample(logits[slot, -1], req)
+                    )
             return
         self._last_tok = greedy  # feed back on-device; no host round-trip
         self._pending.append((greedy, decoding))
@@ -771,15 +782,17 @@ class Engine:
 
     def step(self) -> None:  # repro: hot-loop
         """One engine iteration: arrivals -> admissions (prefill) -> decode."""
-        t0 = self.obs.step_begin()
-        self.sched.poll_arrivals(self.step_count)
-        self._admit_and_prefill()
-        self._decode_once()
-        self.step_count += 1
-        audit = None
-        if self.ec.debug_audit or self.obs.deep:
-            audit = self.kv.audit()
-        self.obs.step_end(self, t0, audit)
+        obs = self.obs
+        with obs.step_span(self.step_count):
+            t0 = obs.step_begin()
+            self._admit_and_prefill()
+            self._decode_once()
+            self.step_count += 1
+            with obs.span("serve.bookkeeping"):
+                audit = None
+                if self.ec.debug_audit or obs.deep:
+                    audit = self.kv.audit()
+                obs.step_end(self, t0, audit)
 
     def run(self, max_steps: int = 1_000_000) -> List[Request]:
         """Drive until every submitted request finishes; returns the

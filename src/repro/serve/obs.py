@@ -13,7 +13,7 @@ recording methods are marked ``# repro: hot-loop`` so staticcheck rule
 RPR002 polices that discipline, and the runtime sanitizer suite proves it
 on a live engine (transfer-guarded steps with observability enabled).
 
-Three layers:
+Four layers:
 
 * :class:`MetricsRegistry` — process-local counters, gauges and fixed-
   bucket histograms (engine steps, decode-batch occupancy, admission-queue
@@ -35,15 +35,23 @@ Three layers:
 * **Chrome-trace/Perfetto export** — :meth:`Observability.chrome_trace`
   emits one engine-step track plus one track per request (span events,
   preemption instants, counter tracks for occupancy/queue/pool), loadable
-  in ``ui.perfetto.dev`` or ``chrome://tracing``.  With deep observability
-  on, the engine additionally wraps its jitted decode/chunk dispatches in
-  ``jax.profiler.TraceAnnotation`` so a device trace captured with
-  ``jax.profiler.trace()`` lines up with the scheduler-event spans.
+  in ``ui.perfetto.dev`` or ``chrome://tracing``.
+* **Step-phase spans on the profiler's clock** — the engine enters a
+  ``jax.profiler`` annotation around each phase of every step
+  (:data:`STEP_SPANS`), always: outside a profiler session one costs about
+  a microsecond, and inside one it lands on the host clock the device
+  events are placed on, so an idle gap in a device trace can be put down
+  to the innermost engine span open across it.  The blocking
+  device->host transfers are ``serve.sync`` spans that also count the
+  arrays they bring over (``host_syncs_total``) and split each step's
+  wall time into host time and sync wait (``step_host_ms`` /
+  ``step_sync_wait_ms``).
 
 Wall timestamps are ``time.perf_counter()`` taken at event-recording time;
 with the engine's deferred-sync design a span therefore measures *dispatch*
 (host) time for async device work — the scheduling view, which is exactly
-what the step-unit columns make deterministic.
+what the step-unit columns make deterministic.  The ``first_token``
+milestone is the exception: it is stamped once the token is on the host.
 
 Validate an exported trace (CI runs this against the serve smoke)::
 
@@ -52,11 +60,12 @@ Validate an exported trace (CI runs this against the serve smoke)::
 from __future__ import annotations
 
 import bisect
-import contextlib
 import dataclasses
 import json
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 # --------------------------------------------------------------------------
 # Metrics registry
@@ -329,23 +338,48 @@ class RequestStats:
 # The engine-facing recorder
 # --------------------------------------------------------------------------
 
-_NULL_CTX = contextlib.nullcontext()
-
-# cumulative engine/pool values mirrored into counters each step:
-# (counter name, attribute path resolved in Observability.step_end)
 _OCCUPANCY_EDGES_DEFAULT = tuple(range(17))
+_STEP_MS_EDGES = (0.1, 0.2, 0.5, 1, 2, 5, 10, 20, 50, 100, 200, 500, 1000)
+
+# the engine's profiler spans: ``serve.step`` holds the others, and
+# ``serve.sync`` sits inside ``serve.sample`` or ``serve.flush`` (a
+# growth-round flush inside ``serve.decode``)
+STEP_SPANS = ("serve.step", "serve.schedule", "serve.prefill",
+              "serve.decode", "serve.sample", "serve.flush", "serve.sync",
+              "serve.bookkeeping")
+
+
+class _SyncSpan:
+    """``serve.sync`` around one blocking device->host transfer of ``n``
+    device arrays: counts them into ``host_syncs_total`` and adds the time
+    the transfer blocks to the running step's sync wait."""
+
+    __slots__ = ("_obs", "_n", "_ann", "_t0")
+
+    def __init__(self, obs: "Observability", n: int):
+        self._obs, self._n = obs, n
+        self._ann = TraceAnnotation("serve.sync")
+
+    def __enter__(self) -> None:  # repro: hot-loop
+        self._ann.__enter__()
+        self._t0 = self._obs._clock()
+
+    def __exit__(self, *exc) -> None:  # repro: hot-loop
+        obs = self._obs
+        obs._sync_s += obs._clock() - self._t0
+        obs._host_syncs.inc(self._n)
+        self._ann.__exit__(*exc)
 
 
 class Observability:
     """Per-engine metrics + span recorder, fed at scheduling events.
 
-    ``deep=False`` (the default) records the always-cheap layer: counters,
-    cheap gauges, spans — pure host int bookkeeping.  ``deep=True``
-    (``EngineConfig.obs``) additionally runs the pool-accounting audit
-    every step (``pages_free/index_pinned/slot_held`` gauges) and wraps
-    the engine's jitted dispatches in ``jax.profiler.TraceAnnotation`` so
-    device traces line up with these host spans.  Neither mode touches
-    device values: enabling observability cannot change engine outputs.
+    Counters, cheap gauges, request spans and the profiler spans of
+    :data:`STEP_SPANS` are always recorded — host bookkeeping that never
+    touches a device value.  ``deep=True`` (``EngineConfig.obs``)
+    additionally runs the pool-accounting audit every step for the
+    ``pages_index_pinned`` / ``pages_slot_held`` gauges.  Neither mode can
+    change engine outputs.
     """
 
     def __init__(self, deep: bool = False, max_seqs: int = 0,
@@ -358,6 +392,7 @@ class Observability:
         self.step_spans: List[Span] = []
         self.max_step_spans = max_step_spans
         self._last_decode_batch = 0
+        self._sync_s = 0.0  # sync wait of the running step, in seconds
         r = self.registry
         # pre-register the full metric set so snapshots are shape-stable
         # between deep on/off runs (deep only changes gauge VALUES)
@@ -375,8 +410,10 @@ class Observability:
             ("cow_copies_total", "copy-on-write page copies"),
             ("pages_allocated_total", "cumulative pool page allocations"),
             ("generated_tokens_total", "tokens generated by finished requests"),
+            ("host_syncs_total", "device arrays brought to the host (serve.sync)"),
         ):
             r.counter(name, help)
+        self._host_syncs = r.counter("host_syncs_total")
         for name, help in (
             ("queue_depth", "requests waiting in the admission queue"),
             ("decode_batch_occupancy", "slots in the current decode batch"),
@@ -397,6 +434,10 @@ class Observability:
         r.histogram("ttft_steps", "arrival -> first token, in engine steps")
         r.histogram("generated_tokens", "tokens generated per finished request",
                     edges=(1, 2, 4, 8, 16, 32, 64, 128))
+        r.histogram("step_host_ms", "engine step wall time less its serve.sync "
+                    "wait, in ms", edges=_STEP_MS_EDGES)
+        r.histogram("step_sync_wait_ms", "engine step time inside serve.sync, "
+                    "in ms", edges=_STEP_MS_EDGES)
         self._counter_base: Dict[str, int] = {}
 
     # -- plumbing ------------------------------------------------------------
@@ -414,15 +455,19 @@ class Observability:
         self.timelines.setdefault(req.rid, tl)
         return tl
 
-    def device_span(self, name: str):
-        """Context manager for a jitted dispatch: a ``jax.profiler``
-        TraceAnnotation when deep observability is on (so device traces
-        align with the host spans), else a shared no-op context."""
-        if not self.deep:
-            return _NULL_CTX
-        import jax.profiler
+    # -- profiler spans (STEP_SPANS) -----------------------------------------
 
-        return jax.profiler.TraceAnnotation(name)
+    def span(self, name: str) -> TraceAnnotation:  # repro: hot-loop
+        """A host span on the profiler's clock around one step phase."""
+        return TraceAnnotation(name)
+
+    def step_span(self, step: int) -> StepTraceAnnotation:  # repro: hot-loop
+        """``serve.step``: the whole of one engine step, numbered."""
+        return StepTraceAnnotation("serve.step", step_num=step)
+
+    def sync(self, n: int = 1) -> _SyncSpan:  # repro: hot-loop
+        """``serve.sync`` around a blocking transfer of ``n`` device arrays."""
+        return _SyncSpan(self, n)
 
     # -- request lifecycle events (called by the scheduler) ------------------
 
@@ -475,8 +520,8 @@ class Observability:
         self.timeline(req).end("prefill-chunk", step, self._clock())
 
     def prefill_complete(self, req, step) -> None:  # repro: hot-loop
-        """Final chunk (or one-shot prefill) done: the first token of this
-        admission is sampled *now*, and the request joins the decode batch."""
+        """Final chunk (or one-shot prefill) done and the first token of
+        this admission on the host: the request joins the decode batch."""
         now = self._clock()
         tl = self.timeline(req)
         tl.end("prefill", step, now)
@@ -492,14 +537,18 @@ class Observability:
         r.histogram("decode_batch_occupancy").observe(occupancy)
 
     def step_begin(self) -> float:  # repro: hot-loop
+        self._sync_s = 0.0
         return self._clock()
 
     def step_end(self, engine, t0, audit=None) -> None:  # repro: hot-loop
         """Per-step bookkeeping at the step boundary (a sync point the
         engine already owns): cumulative counters, cheap gauges, the
-        engine-step span, and — deep only — the audit-backed pool split."""
+        engine-step span, the step's host time and sync wait, and — deep
+        only — the audit-backed pool split."""
         now = self._clock()
         r = self.registry
+        r.histogram("step_host_ms").observe((now - t0 - self._sync_s) * 1e3)
+        r.histogram("step_sync_wait_ms").observe(self._sync_s * 1e3)
         self._sync_counter("engine_steps_total", engine.step_count)
         self._sync_counter("decode_steps_total", engine.decode_steps)
         self._sync_counter("prefill_tokens_total", engine.prefill_tokens)

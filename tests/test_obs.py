@@ -9,10 +9,15 @@ The load-bearing guarantees:
   ``preempted``) and the resume opens fresh ones (no orphans);
 * observability is free of observable effect — greedy outputs and the
   deterministic metrics (counters, step-unit histograms) are bit-identical
-  between ``obs=True`` and ``obs=False`` engines.
+  between ``obs=True`` and ``obs=False`` engines;
+* the profiler spans are always entered and nest inside ``serve.step``,
+  ``host_syncs_total`` counts every device array brought to the host, and
+  a first token is stamped only once it is on the host.
 """
 import dataclasses
+import glob
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +25,7 @@ import numpy as np
 import pytest
 
 import repro.configs as C
+from repro.launch.mesh import make_serve_mesh
 from repro.models import model as M
 from repro.serve import (
     Engine,
@@ -29,8 +35,11 @@ from repro.serve import (
     build_serve_report,
     validate_chrome_trace,
 )
-from repro.serve.obs import Histogram, main as obs_main
+from repro.serve.obs import STEP_SPANS, Histogram, main as obs_main
 from repro.serve.scheduler import Request, RequestStats
+
+
+WALL_HISTOGRAMS = ("step_host_ms", "step_sync_wait_ms")
 
 
 def _paged_cfg(**over):
@@ -261,7 +270,14 @@ def test_obs_on_off_outputs_and_metrics_identical(cfg4, params4):
     outs_on, m_on = run(True)
     assert outs_on == outs_off
     assert m_on["counters"] == m_off["counters"]
-    assert m_on["histograms"] == m_off["histograms"]
+    # the step-time histograms read the wall clock: one observation per
+    # step either way, values free
+    for name, h in m_off["histograms"].items():
+        if name in WALL_HISTOGRAMS:
+            assert m_on["histograms"][name]["count"] == h["count"], name
+        else:
+            assert m_on["histograms"][name] == h, name
+    assert m_on["histograms"].keys() == m_off["histograms"].keys()
     # gauges too — except the two audit-backed ones only deep collection
     # fills (their staying 0 when gated off is exactly the gating contract)
     deep_only = {"pages_index_pinned", "pages_slot_held"}
@@ -380,3 +396,162 @@ def test_serve_report_json_clean_and_consistent(cfg4, params4):
     rep2 = build_serve_report(eng2, done2)
     assert rep2["requests"][0]["decode_tok_s"] is None
     json.dumps(rep2, allow_nan=False)
+
+
+# --------------------------------------------------------------------------
+# Profiler spans, the sync counter and the step's host time
+# --------------------------------------------------------------------------
+
+# four requests over two slots, one of them a single-token answer; each
+# mode's tokens as the engine served them before the step spans existed
+MODES = {
+    "greedy": {},
+    "greedy_eos": {"eos_id": 325},
+    "sampled": {"temperature": 0.8},
+    "sampled_eos": {"temperature": 0.8, "eos_id": 399},
+}
+SERVED = {
+    "greedy": [[440], [416, 19, 111, 2, 153, 113],
+               [426, 466, 325, 302, 304, 325, 27],
+               [463, 55, 283, 162, 192, 237, 495, 470]],
+    "greedy_eos": [[440], [416, 19, 111, 2, 153, 113], [426, 466, 325],
+                   [463, 55, 283, 162, 192, 237, 495, 470]],
+    "sampled": [[229], [411, 423, 399, 334, 81, 123],
+                [311, 498, 74, 421, 250, 204, 97],
+                [263, 76, 243, 5, 244, 300, 273, 492]],
+    "sampled_eos": [[229], [411, 423, 399], [311, 498, 74, 421, 250, 204, 97],
+                    [263, 76, 243, 5, 244, 300, 273, 492]],
+}
+
+
+@pytest.fixture(scope="module", params=list(MODES))
+def served(request, cfg4, params4):
+    mode = request.param
+    rng = np.random.default_rng(7)
+    eng = Engine(cfg4, params4, EngineConfig(
+        max_seqs=2, max_len=40, seed=3, **MODES[mode]))
+    for i, max_new in enumerate((1, 6, 7, 8)):
+        eng.submit(rng.integers(0, cfg4.vocab_size, size=(6 + 3 * i,))
+                   .astype(np.int32), max_new, rid=i, arrival_step=i)
+    done = eng.run()
+    return mode, eng, done
+
+
+def test_served_tokens_unchanged(served):
+    mode, _, done = served
+    assert [list(r.out_tokens) for r in done] == SERVED[mode]
+
+
+def test_host_syncs_total_exact(served):
+    """One sync per first token, plus one per decode step when tokens stay
+    on the device (greedy: the step's tokens come over in one array) or one
+    per decoding slot per step when the host samples."""
+    mode, eng, done = served
+    m = eng.metrics()
+    c = m["counters"]
+    assert c["preemptions_total"] == 0  # every admission samples one first token
+    first_tokens = len(done)
+    if MODES[mode].get("temperature", 0) > 0:
+        per_step = m["histograms"]["decode_batch_occupancy"]["sum"]
+    else:
+        per_step = c["decode_steps_total"]
+    assert c["host_syncs_total"] == per_step + first_tokens
+
+
+def test_step_time_splits_into_host_and_sync_wait(served):
+    _, eng, _ = served
+    h = eng.metrics()["histograms"]
+    host, wait = h["step_host_ms"], h["step_sync_wait_ms"]
+    assert host["count"] == wait["count"] == eng.step_count
+    wall_ms = sum(s.wall_s for s in eng.obs.step_spans) * 1e3
+    assert host["sum"] + wait["sum"] == pytest.approx(wall_ms, rel=1e-9)
+    assert host["sum"] > 0 and wait["sum"] > 0
+
+
+@pytest.mark.parametrize("chunked", [True, False], ids=["chunked", "one_shot"])
+def test_first_token_stamped_after_its_sync(cfg4, params4, chunked):
+    """The first_token milestone (and so ttft_s) is taken once the sampled
+    token is on the host, with the span order a max_new == 1 finish needs."""
+    eng = Engine(cfg4, params4, EngineConfig(
+        max_seqs=2, max_len=40, chunked_prefill=chunked))
+    on_host = {}
+    sample = eng._sample
+
+    def timed(row, req):
+        tok = sample(row, req)
+        on_host.setdefault(req.rid, time.perf_counter())
+        return tok
+
+    eng._sample = timed
+    rng = np.random.default_rng(1)
+    reqs = [eng.submit(rng.integers(0, cfg4.vocab_size, size=(9,))
+                       .astype(np.int32), n, rid=i, arrival_step=i)
+            for i, n in enumerate((1, 4, 3))]
+    eng.run()
+    for r in reqs:
+        assert r.stats.t_first_token >= on_host[r.rid]
+        assert r.timeline.open_spans == []
+
+
+@pytest.mark.parametrize("attr,module", [
+    ("_decode", "jit__paged_step"), ("_chunk_fn", "jit_prefill_chunk"),
+], ids=["decode", "chunk"])
+@pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "mesh"])
+def test_step_programs_lower_under_stable_names(cfg4, params4, attr, module,
+                                                mesh):
+    """A device trace finds the two step programs by these module names."""
+    eng = Engine(cfg4, params4, EngineConfig(max_seqs=2, max_len=16),
+                 mesh=make_serve_mesh("1x1") if mesh else None)
+    jitted, shapes = getattr(eng, attr), []
+
+    def spy(*args):
+        shapes.append(jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args))
+        return jitted(*args)
+
+    setattr(eng, attr, spy)
+    eng.submit(np.arange(6, dtype=np.int32), 2, rid=0)
+    eng.run()
+    assert jitted.lower(*shapes[0]).as_text().split()[:2] == ["module", f"@{module}"]
+
+
+def test_profiler_capture_nests_spans_in_steps(cfg4, params4, tmp_path):
+    """A CPU profiler capture, deep observability off: every engine span is
+    on the host trace inside a numbered ``serve.step``, and every
+    ``serve.sync`` inside the sampling or flush span that waits on it."""
+    eng = Engine(cfg4, params4, EngineConfig(max_seqs=2, max_len=40,
+                                             eos_id=1))
+    rng = np.random.default_rng(2)
+    for i in range(2):
+        eng.submit(rng.integers(0, cfg4.vocab_size, size=(6,)).astype(np.int32),
+                   3, rid=i)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = [
+        (e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+        for plane in jax.profiler.ProfileData.from_file(path).planes
+        for line in plane.lines for e in line.events
+        if e.name.startswith("serve.")
+    ]
+    assert {name for name, *_ in events} == set(STEP_SPANS)
+    steps = [(s, e) for name, s, e, _ in events if name == "serve.step"]
+    assert sorted(st["step_num"] for name, *_, st in events
+                  if name == "serve.step") == list(range(eng.step_count))
+
+    def inside(s, e, outer):
+        return any(a <= s and e <= b for a, b in outer)
+
+    for name, s, e, _ in events:
+        if name != "serve.step":
+            assert inside(s, e, steps), name
+    waits = [(s, e) for name, s, e, _ in events
+             if name in ("serve.sample", "serve.flush")]
+    syncs = [(s, e) for name, s, e, _ in events if name == "serve.sync"]
+    assert syncs and all(inside(s, e, waits) for s, e in syncs)
+    assert len(syncs) == eng.metrics()["counters"]["host_syncs_total"]

@@ -8,6 +8,7 @@ a fixture, never at import, so that only the process that runs these tests
 loads the TPU library; where it cannot be described the tests skip.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -43,12 +44,18 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, one_chip, *shapes, donate=()):
+def _compile(fn, one_chip, *shapes, donate=(), kernel=""):
+    """Compile ``fn``; with ``kernel``, the Pallas call must keep that name
+    on a ``tpu_custom_call`` op, which is how a device trace finds it."""
     args = [
         jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes
     ]
     compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if kernel:
+        assert re.search(rf'%{kernel}(\.\d+)? = .*custom_call_target='
+                         rf'"tpu_custom_call"', text), kernel
     return compiled
 
 
@@ -61,6 +68,7 @@ def test_paged_attention_decode_minicpm_2b(one_chip):
         ((NUM_PAGES, PAGE, 36, 64), jnp.bfloat16),
         ((SLOTS, MAX_PAGES), jnp.int32),
         ((SLOTS,), jnp.int32),
+        kernel="paged_attention_decode",
     )
 
 
@@ -75,6 +83,7 @@ def test_mla_paged_attention_decode_deepseek_v3(one_chip):
         ((NUM_PAGES, PAGE, 64), jnp.bfloat16),
         ((SLOTS, MAX_PAGES), jnp.int32),
         ((SLOTS,), jnp.int32),
+        kernel="mla_paged_attention_decode",
     )
 
 
@@ -91,7 +100,7 @@ def test_paged_copy_stacked_pools(one_chip, pool):
     compiled = _compile(
         functools.partial(paged_copy, interpret=False), one_chip,
         (pool, jnp.bfloat16), ((), jnp.int32), ((), jnp.int32),
-        donate=(0,),
+        donate=(0,), kernel="paged_copy",
     )
     ma = compiled.memory_analysis()
     pool_bytes = 2 * int(jnp.prod(jnp.asarray(pool)))
