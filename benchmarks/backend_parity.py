@@ -26,6 +26,7 @@ import numpy as np
 from benchmarks.common import emit, timed
 from repro.core import encoder as enc
 from repro.core.backend import BACKENDS, resolve_backend
+from repro.kernels.paged_attention import kv_page_order
 
 
 def run(scale: float = 1.0, block: int = 128):
@@ -52,16 +53,18 @@ def run(scale: float = 1.0, block: int = 128):
     run_paged(scale)
 
 
-def _paged_layout(rng, B, maxp, page, leaf_shapes):
+def _paged_layout(rng, B, maxp, page, leaf_shapes, layers=2):
     """A serving-shaped paged layout: per-slot table rows of distinct
-    physical pages (page 0 reserved as the null page) + random pools."""
+    physical pages (page 0 reserved as the null page) + random
+    layer-stacked pools ``(layers, num_pages, *shape)``."""
     num_pages = B * maxp + 1
     table = np.zeros((B, maxp), np.int32)
     phys = rng.permutation(np.arange(1, num_pages))
     for b in range(B):
         table[b] = phys[b * maxp:(b + 1) * maxp]
     pools = [
-        jnp.asarray(rng.standard_normal((num_pages,) + s), jnp.float32)
+        jnp.asarray(rng.standard_normal((layers, num_pages) + s),
+                    jnp.float32)
         for s in leaf_shapes
     ]
     return jnp.asarray(table), pools
@@ -76,6 +79,8 @@ def run_paged(scale: float = 1.0, page: int = 8):
     """
     print("# paged decode: fused kernels vs jnp gather oracle per page count")
     B, H, hkv, dh = 2, 8, 4, 32
+    kv_block = tuple((page, hkv, dh)[a] for a in kv_page_order(dh))
+    layer = jnp.int32(1)  # the pools are stacked; read their second layer
     r, dr = 32, 16
     scale_mla = (r + dr) ** -0.5
     ref, pal = resolve_backend("reference"), resolve_backend("pallas")
@@ -96,13 +101,13 @@ def run_paged(scale: float = 1.0, page: int = 8):
         seq_pos = jnp.full((B,), maxp * page - 1, jnp.int32)
         # dense/GQA
         table, (k_pages, v_pages) = _paged_layout(
-            rng, B, maxp, page, [(page, hkv, dh)] * 2
+            rng, B, maxp, page, [kv_block] * 2
         )
         q = jnp.asarray(rng.standard_normal((B, 1, H, dh)), jnp.float32)
         y_ref, us_ref = timed(lambda: np.asarray(
-            f_gqa["reference"](q, k_pages, v_pages, table, seq_pos)))
+            f_gqa["reference"](q, k_pages, v_pages, table, seq_pos, layer)))
         y_pal, us_pal = timed(lambda: np.asarray(
-            f_gqa["pallas"](q, k_pages, v_pages, table, seq_pos)))
+            f_gqa["pallas"](q, k_pages, v_pages, table, seq_pos, layer)))
         err = float(np.abs(y_pal - y_ref).max())
         gathered = 2 * B * maxp * page * hkv * dh * 4  # ref K+V HBM bytes
         emit(f"paged/gqa_p{maxp}/reference_us", us_ref,
@@ -117,10 +122,10 @@ def run_paged(scale: float = 1.0, page: int = 8):
         q_rope = jnp.asarray(rng.standard_normal((B, 1, H, dr)), jnp.float32)
         y_ref, us_ref = timed(lambda: np.asarray(
             f_mla["reference"](q_lat, q_rope, ckv_pages, kr_pages, table,
-                               seq_pos)))
+                               seq_pos, layer)))
         y_pal, us_pal = timed(lambda: np.asarray(
             f_mla["pallas"](q_lat, q_rope, ckv_pages, kr_pages, table,
-                            seq_pos)))
+                            seq_pos, layer)))
         err = float(np.abs(y_pal - y_ref).max())
         emit(f"paged/mla_p{maxp}/reference_us", us_ref, "")
         emit(f"paged/mla_p{maxp}/pallas_us", us_pal,
@@ -128,7 +133,7 @@ def run_paged(scale: float = 1.0, page: int = 8):
     # COW page copy (page-count independent: one page moves)
     rng = np.random.default_rng(0)
     pool = {"k_pages": jnp.asarray(
-        rng.standard_normal((4, 9, page, hkv, dh)), jnp.float32)}
+        rng.standard_normal((4, 9) + kv_block), jnp.float32)}
     f_ref = jax.jit(ref.paged_copy_page)
     f_pal = jax.jit(pal.paged_copy_page)
     y_ref, us_ref = timed(lambda: np.asarray(

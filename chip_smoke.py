@@ -224,9 +224,11 @@ def check_run(eng, done, reqs) -> None:
 
 
 def memory_report(eng) -> None:
-    """Compile the decode and COW steps ahead of time and print their
-    memory analysis (per device under a mesh; the pool-sized temp is a
-    known layout cost) and every device's peak memory."""
+    """Compile the decode and COW steps ahead of time, print their memory
+    analysis (per device under a mesh) and every device's peak memory, and
+    hold each step's temp under a twentieth of the pool: the steps update
+    the donated pool where it lies, in its stored page order, and keep no
+    pool-sized buffer beside it."""
     import jax
     import jax.numpy as jnp
 
@@ -248,6 +250,9 @@ def memory_report(eng) -> None:
               f"device, temp/pool {ma.temp_size_in_bytes / pool:.3f}); "
               f"{kernels} tpu_custom_call", flush=True)
         check(kernels >= 1, f"{name} has no compiled Pallas kernel")
+        check(ma.temp_size_in_bytes < pool / 20,
+              f"{name} holds {ma.temp_size_in_bytes} B of temp beside a "
+              f"{pool} B pool: the pool is copied, not updated in place")
     for d in jax.devices():
         stats = d.memory_stats() or {}
         print(f"device {d.id} peak_bytes_in_use "

@@ -44,8 +44,8 @@ class InventoryConfig:
     #: specs compile (``cfg.decode_backend``).  The default is the
     #: kernelized pallas path — the serving hot loop this analysis exists
     #: to budget: streaming pages through the fused kernel removes the
-    #: whole-history ``k_pages[page_table]`` gather from the lowered step,
-    #: which is exactly the RPJ102 ``max_gather_bytes`` drop the paper's
+    #: whole-history ``k_pages[layer, page_table]`` gather from the lowered
+    #: step, which is exactly the RPJ102 ``max_gather_bytes`` drop the paper's
     #: arrangement argument predicts.  The jnp oracle path stays gated as
     #: ``decode_step_reference`` / ``cow_copy_reference``.
     backend: str = "pallas"

@@ -54,6 +54,7 @@ from repro.kernels.bwma_layernorm import bwma_layernorm
 from repro.kernels.bwma_softmax import bwma_softmax
 from repro.kernels.bwma_transpose import bwma_transpose
 from repro.kernels.paged_attention import (
+    kv_head_axis,
     mla_paged_attention_decode,
     paged_attention_decode,
     paged_copy,
@@ -86,16 +87,21 @@ class Backend(Protocol):
 
     # -- serving-engine paged-decode operators (raw arrays, not Blocked:
     # -- the engine's pages already ARE kernel tiles — page size is
-    # -- cfg.block — so there is no separate blocked arrangement step) --
+    # -- cfg.block — so there is no separate blocked arrangement step).
+    # -- Pools are layer-stacked (L, num_pages, *block); the decode reads
+    # -- take the layer index, the COW copy moves a page in every layer,
+    # -- and ``head_axis`` names the stacked pools' kv-head axis (None:
+    # -- the pools have none) --
 
     def paged_attention_decode(self, q, k_pages, v_pages, page_table,
-                               seq_pos): ...
+                               seq_pos, layer): ...
 
     def mla_paged_attention_decode(self, q_lat, q_rope, ckv_pages,
-                                   krope_pages, page_table, seq_pos, *,
-                                   scale): ...
+                                   krope_pages, page_table, seq_pos, layer,
+                                   *, scale): ...
 
-    def paged_copy_page(self, pools: Dict, src, dst) -> Dict: ...
+    def paged_copy_page(self, pools: Dict, src, dst,
+                        head_axis: Optional[int] = None) -> Dict: ...
 
     # -- layout-neutral element-wise ops (shared implementations) --
 
@@ -154,24 +160,26 @@ class ReferenceBackend(_ElementwiseMixin):
     # direction).
 
     def paged_attention_decode(self, q, k_pages, v_pages, page_table,
-                               seq_pos):
+                               seq_pos, layer):
         from repro.models import attention as attn
 
         return attn.paged_gather_attend(
-            q, k_pages, v_pages, page_table, seq_pos
+            q, k_pages, v_pages, page_table, seq_pos, layer
         )
 
     def mla_paged_attention_decode(self, q_lat, q_rope, ckv_pages,
-                                   krope_pages, page_table, seq_pos, *,
-                                   scale):
+                                   krope_pages, page_table, seq_pos, layer,
+                                   *, scale):
         from repro.models import attention as attn
 
         return attn.mla_paged_gather_attend(
             q_lat, q_rope, ckv_pages, krope_pages, page_table, seq_pos,
-            scale=scale,
+            layer, scale=scale,
         )
 
-    def paged_copy_page(self, pools: Dict, src, dst) -> Dict:
+    def paged_copy_page(self, pools: Dict, src, dst,
+                        head_axis: Optional[int] = None) -> Dict:
+        # jnp ops: GSPMD partitions them whatever the pools' sharding
         from repro.models import attention as attn
 
         return attn.paged_copy_page(pools, src, dst)
@@ -265,24 +273,30 @@ class PallasBackend(_ElementwiseMixin):
             return pol
         return None
 
+    @staticmethod
+    def _axis_spec(ndim: int, axis: int, name: str):
+        return _P(*(name if a == axis else None for a in range(ndim)))
+
     def paged_attention_decode(self, q, k_pages, v_pages, page_table,
-                               seq_pos):
-        pol = self._tp_policy(q.shape[2], k_pages.shape[2])
+                               seq_pos, layer):
+        axis = kv_head_axis(q.shape[-1])
+        pol = self._tp_policy(q.shape[2], k_pages.shape[axis])
         if pol is not None:
             tp = pol.tp_axis
             head = _P(None, None, tp, None)
+            pool = self._axis_spec(k_pages.ndim, axis, tp)
             return jax.shard_map(
                 self._paged_attention_decode, mesh=pol.mesh,
-                in_specs=(head, head, head, _P(), _P()),
+                in_specs=(head, pool, pool, _P(), _P(), _P()),
                 out_specs=head, check_vma=False,
-            )(q, k_pages, v_pages, page_table, seq_pos)
+            )(q, k_pages, v_pages, page_table, seq_pos, layer)
         return self._paged_attention_decode(
-            q, k_pages, v_pages, page_table, seq_pos
+            q, k_pages, v_pages, page_table, seq_pos, layer
         )
 
     def mla_paged_attention_decode(self, q_lat, q_rope, ckv_pages,
-                                   krope_pages, page_table, seq_pos, *,
-                                   scale):
+                                   krope_pages, page_table, seq_pos, layer,
+                                   *, scale):
         pol = self._tp_policy(q_lat.shape[2])
         if pol is not None:
             # latent pages carry no head axis: they stay replicated and each
@@ -293,23 +307,25 @@ class PallasBackend(_ElementwiseMixin):
                     self._mla_paged_attention_decode, scale=scale
                 ),
                 mesh=pol.mesh,
-                in_specs=(head, head, _P(), _P(), _P(), _P()),
+                in_specs=(head, head, _P(), _P(), _P(), _P(), _P()),
                 out_specs=head, check_vma=False,
-            )(q_lat, q_rope, ckv_pages, krope_pages, page_table, seq_pos)
+            )(q_lat, q_rope, ckv_pages, krope_pages, page_table, seq_pos,
+              layer)
         return self._mla_paged_attention_decode(
             q_lat, q_rope, ckv_pages, krope_pages, page_table, seq_pos,
-            scale=scale,
+            layer, scale=scale,
         )
 
-    def paged_copy_page(self, pools: Dict, src, dst) -> Dict:
+    def paged_copy_page(self, pools: Dict, src, dst,
+                        head_axis: Optional[int] = None) -> Dict:
         out = {}
         for name, pool in pools.items():
-            # stacked dense/GQA pools (L, pages, page, Hkv, dh) COW-copy per
-            # head shard; headless pools (MLA latent) copy replicated
-            pol = (self._tp_policy(pool.shape[3])
-                   if pool.ndim == 5 else None)
+            # pools with a kv-head axis (dense/GQA K/V) COW-copy per head
+            # shard; headless pools (MLA latent) copy replicated
+            pol = (self._tp_policy(pool.shape[head_axis])
+                   if head_axis is not None else None)
             if pol is not None:
-                spec = _P(None, None, None, pol.tp_axis, None)
+                spec = self._axis_spec(pool.ndim, head_axis, pol.tp_axis)
                 out[name] = jax.shard_map(
                     self._paged_copy, mesh=pol.mesh,
                     in_specs=(spec, _P(), _P()),

@@ -4,13 +4,14 @@ The engine sizes KV-cache pages to ``cfg.block`` — the accelerator kernel's
 native tile — precisely so the serving hot loop can consume them *in place*.
 These kernels close that loop.  The reference decode path gathers every
 slot's whole logical history into a dense HBM buffer
-(``k_pages[page_table]`` — ``max_pages * page`` tokens per slot per layer
-per step) before attending; here the grid walks each slot's page-table row
-instead and the BlockSpec index map streams exactly one physical page into
-VMEM per grid step:
+(``k_pages[layer, page_table]`` — ``max_pages * page`` tokens per slot per
+layer per step) before attending; here the grid walks each slot's
+page-table row instead and the BlockSpec index map streams exactly one
+physical page of one layer into VMEM per grid step:
 
-    in_specs=[..., pl.BlockSpec((1, page, ...),
-                   lambda b, j, table, seqpos: (table[b, j], 0, 0, 0))]
+    in_specs=[..., pl.BlockSpec((None, None) + block,
+                   lambda b, j, table, seqpos, layer:
+                       (layer[0], table[b, j], 0, 0, 0))]
 
 so the gathered history never exists in HBM at all — the jaxcheck RPJ102
 ``max_gather_bytes`` budget on the decode step drops from the full gathered
@@ -21,6 +22,22 @@ Keys past a slot's current position (partial-page tails, unmapped null-page
 entries, stale pages of retired requests) are masked with the same
 ``finfo.min`` fill as the reference path, so parity holds to fused-softmax
 reassociation (<= 1e-6, the PR-1 BWMA tolerance).
+
+**The pool the kernels read.**  Pools are layer-stacked, ``(L, num_pages,
+*block)``, and the engine carries them whole through its layer scan: the
+decode kernels take the stacked pool plus the layer index as a
+scalar-prefetch operand, so no per-layer slice of the pool is ever cut out
+or written back.  A dense/GQA K/V page holds the logical axes ``(page,
+n_kv_heads, d_head)`` in the order :func:`kv_page_order` picks from the
+head dim, so that the page's minor dims fill TPU tiles as stored: a head
+dim that fills the 128 lanes keeps ``(page, hkv, dh)`` (starcoder2's
+``(128, 4, 128)``); any other head dim gives the lanes to the page axis,
+which the engine sizes to the kernel's 128-token tile, and stores ``(hkv,
+dh, page)`` (minicpm-2b's ``(36, 64, 128)``; row-major ``(128, 36, 64)``
+would have XLA relayout the pool around every kernel call).  The kernel
+reads ``(hkv, dh, page)`` blocks straight into the MXU, with no in-VMEM
+transpose.  MLA latent pages keep ``(page, r)``: 512 and 64 lanes are
+already aligned blocks.
 
 Three kernels:
 
@@ -59,13 +76,42 @@ from jax.experimental.pallas import tpu as pltpu
 _MASK = jnp.finfo(jnp.float32).min
 
 
+# the TPU vector lane count: a stored minor dim fills tiles when it is a
+# multiple of this
+LANES = 128
+
+
+def kv_page_order(d_head: int) -> tuple:
+    """Stored order of a K/V page's logical ``(page, hkv, dh)`` axes.
+
+    The minor dim of a stored page should be a multiple of the 128 lanes
+    (and the second-minor one fill the sublanes), else XLA's TPU layout
+    assignment stores the pool in another order and relayouts it around
+    every kernel call.  A head dim that fills the lanes keeps the row-major
+    ``(page, hkv, dh)``; any other gives the lanes to the page axis, which
+    the engine sizes to the kernel's 128-token tile, with ``dh`` on the
+    sublanes: ``(hkv, dh, page)``.  For minicpm-2b's ``(128, 36, 64)`` and
+    starcoder2's ``(128, 4, 128)`` pages this is the order
+    :func:`_tiled_order` picks; it is fixed by the head dim alone so that
+    every reader (this kernel, the reference gather, the tensor-parallel
+    specs) can tell a pool's order from the head dim.
+    """
+    return (0, 1, 2) if d_head % LANES == 0 else (1, 2, 0)
+
+
+def kv_head_axis(d_head: int) -> int:
+    """The kv-head axis of a layer-stacked ``(L, num_pages, *block)`` K/V
+    pool: the axis tensor parallelism shards."""
+    return 2 + kv_page_order(d_head).index(1)
+
+
 # --------------------------------------------------------------------------
 # Dense / GQA paged decode
 # --------------------------------------------------------------------------
 
-def _gqa_decode_kernel(table_ref, seqpos_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, *, page: int, maxp: int,
-                       groups: int, scale: float):
+def _gqa_decode_kernel(table_ref, seqpos_ref, layer_ref, q_ref, k_ref, v_ref,
+                       o_ref, acc_ref, m_ref, l_ref, *, page: int, maxp: int,
+                       groups: int, scale: float, page_minor: bool):
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -76,14 +122,19 @@ def _gqa_decode_kernel(table_ref, seqpos_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0].astype(jnp.float32)        # (H, dh)
-    k = k_ref[0].astype(jnp.float32)        # (page, Hkv, dh)
-    v = v_ref[0].astype(jnp.float32)
+    k = k_ref[...].astype(jnp.float32)      # one stored page
+    v = v_ref[...].astype(jnp.float32)
     H, dh = q.shape
     hkv = H // groups
     qg = q.reshape(hkv, groups, dh)
+    if not page_minor:
+        # (page, Hkv, dh) pages: bring K to (Hkv, dh, page), V to
+        # (Hkv, page, dh) for the batched matmuls below
+        k = k.transpose(1, 2, 0)
+        v = v.transpose(1, 0, 2)
     # per-page grouped scores: (Hkv, g, dh) x (Hkv, dh, page) -> (Hkv, g, page)
     s = jax.lax.dot_general(
-        qg, k.transpose(1, 2, 0), (((2,), (1,)), ((0,), (0,))),
+        qg, k, (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     ) * scale
     # this page covers absolute positions [j*page, (j+1)*page); mask beyond
@@ -98,9 +149,10 @@ def _gqa_decode_kernel(table_ref, seqpos_ref, q_ref, k_ref, v_ref, o_ref,
     alpha = jnp.exp(m_prev - m_new)
     p_att = jnp.exp(s - m_new)              # (H, page)
     l_ref[...] = l_prev * alpha + jnp.sum(p_att, axis=-1, keepdims=True)
+    # (Hkv, g, page) x V over its page axis -> (Hkv, g, dh)
     pv = jax.lax.dot_general(
-        p_att.reshape(hkv, groups, page), v.transpose(1, 0, 2),
-        (((2,), (1,)), ((0,), (0,))),
+        p_att.reshape(hkv, groups, page), v,
+        (((2,), (2 if page_minor else 1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     ).reshape(H, dh)
     acc_ref[...] = acc_ref[...] * alpha + pv
@@ -113,33 +165,42 @@ def _gqa_decode_kernel(table_ref, seqpos_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)  # always valid)
 
 
-def paged_attention_decode(q, k_pages, v_pages, page_table, seq_pos, *,
-                           scale: Optional[float] = None,
-                           interpret: bool = False):
-    """Fused one-token GQA decode over the block-paged K/V pool.
+def _layer_operand(layer):
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
-    ``q``: (B, 1, H, dh); ``k_pages``/``v_pages``: (num_pages, page, Hkv,
-    dh); ``page_table``: (B, max_pages) int32; ``seq_pos``: (B,) int32.
-    Returns (B, 1, H, dh) in ``q.dtype`` — the same contract as the
-    reference gather + ``decode_attention`` read (write happens outside).
+
+def paged_attention_decode(q, k_pages, v_pages, page_table, seq_pos, layer,
+                           *, scale: Optional[float] = None,
+                           interpret: bool = False):
+    """Fused one-token GQA decode over one layer of the stacked K/V pool.
+
+    ``q``: (B, 1, H, dh); ``k_pages``/``v_pages``: (L, num_pages, *block),
+    each page's block in :func:`kv_page_order` ``(dh)``; ``page_table``:
+    (B, max_pages) int32; ``seq_pos``: (B,) int32; ``layer``: the scalar
+    index into L, scalar-prefetched so the index map addresses the stacked
+    pool directly.  Returns (B, 1, H, dh) in ``q.dtype`` — the same
+    contract as the reference gather + ``decode_attention`` read (write
+    happens outside).
     """
     B, S, H, dh = q.shape
     assert S == 1
-    num_pages, page, hkv, _ = k_pages.shape
+    order = kv_page_order(dh)
+    block = k_pages.shape[2:]
+    page, hkv = block[order.index(0)], block[order.index(1)]
     maxp = page_table.shape[1]
     scale = dh ** -0.5 if scale is None else scale
+    # ONE physical page of one layer per grid step, straight from the table
+    page_spec = pl.BlockSpec((None, None) + block,
+                             lambda b, j, t, sp, ly: (ly[0], t[b, j], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # page_table, seq_pos
+        num_scalar_prefetch=3,  # page_table, seq_pos, layer
         grid=(B, maxp),
         in_specs=[
-            pl.BlockSpec((1, H, dh), lambda b, j, t, sp: (b, 0, 0)),
-            # ONE physical page per grid step, straight from the table row
-            pl.BlockSpec((1, page, hkv, dh),
-                         lambda b, j, t, sp: (t[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, page, hkv, dh),
-                         lambda b, j, t, sp: (t[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, H, dh), lambda b, j, t, sp, ly: (b, 0, 0)),
+            page_spec,
+            page_spec,
         ],
-        out_specs=pl.BlockSpec((1, H, dh), lambda b, j, t, sp: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, dh), lambda b, j, t, sp, ly: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H, dh), jnp.float32),  # weighted-value accumulator
             pltpu.VMEM((H, 1), jnp.float32),   # running max
@@ -149,14 +210,14 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_pos, *,
     out = pl.pallas_call(
         functools.partial(
             _gqa_decode_kernel, page=page, maxp=maxp, groups=H // hkv,
-            scale=scale,
+            scale=scale, page_minor=order[-1] == 0,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, dh), q.dtype),
         interpret=interpret,
         name="paged_attention_decode",
     )(page_table.astype(jnp.int32), seq_pos.astype(jnp.int32),
-      q[:, 0], k_pages, v_pages)
+      _layer_operand(layer), q[:, 0], k_pages, v_pages)
     return out[:, None]
 
 
@@ -164,8 +225,9 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_pos, *,
 # MLA paged decode (absorbed-matmul over streamed latent pages)
 # --------------------------------------------------------------------------
 
-def _mla_decode_kernel(table_ref, seqpos_ref, ql_ref, qr_ref, ckv_ref,
-                       kr_ref, o_ref, acc_ref, m_ref, l_ref, *, page: int,
+def _mla_decode_kernel(table_ref, seqpos_ref, layer_ref, ql_ref, qr_ref,
+                       ckv_ref, kr_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                       page: int,
                        maxp: int, scale: float):
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -178,8 +240,8 @@ def _mla_decode_kernel(table_ref, seqpos_ref, ql_ref, qr_ref, ckv_ref,
 
     q_lat = ql_ref[0].astype(jnp.float32)   # (H, r)
     q_rope = qr_ref[0].astype(jnp.float32)  # (H, dr)
-    ckv = ckv_ref[0].astype(jnp.float32)    # (page, r)
-    kr = kr_ref[0].astype(jnp.float32)      # (page, dr)
+    ckv = ckv_ref[...].astype(jnp.float32)  # (page, r)
+    kr = kr_ref[...].astype(jnp.float32)    # (page, dr)
     # absorbed scores against this page's latents: (H, page)
     s = jax.lax.dot_general(
         q_lat, ckv, (((1,), (1,)), ((), ())),
@@ -214,32 +276,36 @@ def _mla_decode_kernel(table_ref, seqpos_ref, ql_ref, qr_ref, ckv_ref,
 
 
 def mla_paged_attention_decode(q_lat, q_rope, ckv_pages, krope_pages,
-                               page_table, seq_pos, *, scale: float,
+                               page_table, seq_pos, layer, *, scale: float,
                                interpret: bool = False):
-    """Fused one-token MLA decode over the block-paged *latent* pool.
+    """Fused one-token MLA decode over one layer of the stacked *latent*
+    pool.
 
     ``q_lat``: (B, 1, H, r) — q_nope already absorbed through ``W_kv_b``;
-    ``q_rope``: (B, 1, H, dr); ``ckv_pages``: (num_pages, page, r);
-    ``krope_pages``: (num_pages, page, dr).  Returns the latent-space
-    attention output ``o_lat`` (B, 1, H, r) in ``ckv_pages.dtype`` — the
-    caller applies the value expansion (a per-token matmul, not a paged
-    read).
+    ``q_rope``: (B, 1, H, dr); ``ckv_pages``: (L, num_pages, page, r);
+    ``krope_pages``: (L, num_pages, page, dr); ``layer``: the scalar index
+    into L (scalar-prefetched, like the page table).  Returns the
+    latent-space attention output ``o_lat`` (B, 1, H, r) in
+    ``ckv_pages.dtype`` — the caller applies the value expansion (a
+    per-token matmul, not a paged read).
     """
     B, S, H, r = q_lat.shape
     assert S == 1
-    num_pages, page, _ = ckv_pages.shape
+    page = ckv_pages.shape[2]
     dr = q_rope.shape[-1]
     maxp = page_table.shape[1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # page_table, seq_pos
+        num_scalar_prefetch=3,  # page_table, seq_pos, layer
         grid=(B, maxp),
         in_specs=[
-            pl.BlockSpec((1, H, r), lambda b, j, t, sp: (b, 0, 0)),
-            pl.BlockSpec((1, H, dr), lambda b, j, t, sp: (b, 0, 0)),
-            pl.BlockSpec((1, page, r), lambda b, j, t, sp: (t[b, j], 0, 0)),
-            pl.BlockSpec((1, page, dr), lambda b, j, t, sp: (t[b, j], 0, 0)),
+            pl.BlockSpec((1, H, r), lambda b, j, t, sp, ly: (b, 0, 0)),
+            pl.BlockSpec((1, H, dr), lambda b, j, t, sp, ly: (b, 0, 0)),
+            pl.BlockSpec((None, None, page, r),
+                         lambda b, j, t, sp, ly: (ly[0], t[b, j], 0, 0)),
+            pl.BlockSpec((None, None, page, dr),
+                         lambda b, j, t, sp, ly: (ly[0], t[b, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, H, r), lambda b, j, t, sp: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, r), lambda b, j, t, sp, ly: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H, r), jnp.float32),   # o_lat accumulator
             pltpu.VMEM((H, 1), jnp.float32),   # running max
@@ -254,7 +320,8 @@ def mla_paged_attention_decode(q_lat, q_rope, ckv_pages, krope_pages,
         interpret=interpret,
         name="mla_paged_attention_decode",
     )(page_table.astype(jnp.int32), seq_pos.astype(jnp.int32),
-      q_lat[:, 0], q_rope[:, 0], ckv_pages, krope_pages)
+      _layer_operand(layer), q_lat[:, 0], q_rope[:, 0], ckv_pages,
+      krope_pages)
     return out[:, None]
 
 
@@ -272,12 +339,14 @@ def _tiled_order(shape) -> tuple:
     Within one page the minor dim should be a multiple of the 128-lane
     width, and the second-minor one a multiple or a divisor of the 8-row
     sublane count.  XLA's TPU layout assignment stores a pool in such an
-    order (minicpm-2b's ``(L, P, 128, 36, 64)`` pool sits page-axis-minor
-    in HBM), so the transpose into it is a bitcast there, and a kernel
-    reading the transposed view needs no pool-sized relayout copy.
+    order, so the transpose into it is a bitcast there, and a kernel
+    reading the transposed view needs no pool-sized relayout copy.  Dense
+    K/V pools are stored tiled already (:func:`kv_page_order`), so their
+    view is the identity; MLA's ``(page, 64)`` rope-key pages are read
+    ``(64, page)``.
     """
     axes = list(range(2, len(shape)))
-    minor = next((a for a in reversed(axes) if shape[a] % 128 == 0),
+    minor = next((a for a in reversed(axes) if shape[a] % LANES == 0),
                  axes[-1])
     axes.remove(minor)
     if axes:
@@ -291,7 +360,7 @@ def _tiled_order(shape) -> tuple:
 def paged_copy(pool, src, dst, *, interpret: bool = False):
     """Copy physical page ``src`` -> ``dst`` in one stacked page pool.
 
-    ``pool``: (L, num_pages, page, ...) — any paged leaf (dense K/V or MLA
+    ``pool``: (L, num_pages, *block) — any paged leaf (dense K/V or MLA
     latent; the page axis is axis 1 after layer stacking).  The kernel
     reads the pool through its tile-aligned view (:func:`_tiled_order`),
     one whole page block per grid step, one step per stacked layer;

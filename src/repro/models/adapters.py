@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core.backend import resolve_backend
+from repro.kernels.paged_attention import kv_head_axis, kv_page_order
 from repro.models import attention as attn
 from repro.models import ssm as ssmm
 
@@ -92,18 +93,22 @@ def write_slot_rows(seg_cache: Dict, rows: Dict, slot, *, axis: int = 0) -> Dict
 
 
 def _install_paged(dst: Dict, src: Dict, phys_tok, off_tok,
-                   names: Dict[str, str]) -> Dict:
+                   names: Dict[str, str], page_axis: int = 0) -> Dict:
     """Scatter (L, S)-shaped prefill tensors per token into physical pages.
 
-    ``names`` maps prefill-cache keys to pool keys (e.g. ``k -> k_pages``).
-    Tokens past the slot's allocation arrive mapped to the null page (the
-    bucketed-prefill pad tail), whose content is garbage by design.
+    ``names`` maps prefill-cache keys to pool keys (e.g. ``k -> k_pages``);
+    ``page_axis`` is the token axis within a stored page.  Tokens past the
+    slot's allocation arrive mapped to the null page (the bucketed-prefill
+    pad tail), whose content is garbage by design.
     """
     out = dict(dst)
     for s_name, p_name in names.items():
-        x = src[s_name][:, 0]  # (L, S, ...)
-        out[p_name] = dst[p_name].at[:, phys_tok, off_tok].set(
-            x.astype(dst[p_name].dtype)
+        x = src[s_name][:, 0]  # (L, S, ...): one run of S tokens per layer
+        L = x.shape[0]
+        out[p_name] = attn.write_page_tokens(
+            dst[p_name], jnp.arange(L),
+            jnp.broadcast_to(phys_tok, (L,) + phys_tok.shape),
+            jnp.broadcast_to(off_tok, (L,) + off_tok.shape), x, page_axis,
         )
     return out
 
@@ -119,6 +124,12 @@ class CacheAdapter:
     layer-parameter subtree that drives it.  ``paged`` adapters draw on the
     shared physical page pool (page accounting in the allocator covers
     them); non-paged adapters own ``max_seqs`` per-slot rows.
+
+    The engine's layer scan treats the two apart: a paged adapter's pools
+    ride the scan's carry whole, so its ``chunk`` / ``decode`` receive the
+    layer-stacked ``(L, num_pages, ...)`` pool plus the scalar ``layer``
+    index and write only the tokens they add; a non-paged adapter receives
+    this layer's rows, sliced by the scan, and ignores ``layer``.
     """
 
     key: str = ""
@@ -181,11 +192,12 @@ class CacheAdapter:
     def chunk(self, p: Dict, cfg: ModelConfig, h, positions, cache: Dict,
               ctx: Dict, pos_offset):
         """One prompt chunk of one slot.  ``ctx`` carries {slot, first,
-        table_row, phys_tok, off_tok}.  Returns (mixer_out, new_cache)."""
+        table_row, phys_tok, off_tok, layer}.  Returns (mixer_out,
+        new_cache)."""
         raise NotImplementedError
 
     def decode(self, p: Dict, cfg: ModelConfig, h, positions, cache: Dict,
-               *, seq_pos, page_table, active):
+               *, seq_pos, page_table, active, layer):
         """One lockstep decode step, every slot at its own position.
         Inactive slots' cache writes must be dropped (null page / OOB
         index / where-mask).  Returns (mixer_out, new_cache)."""
@@ -202,27 +214,39 @@ class PagedAttnAdapter(CacheAdapter):
     shareable = True
 
     def init_pool(self, cfg, geom):
+        """One layer's K/V pages, each page's ``(page, n_kv_heads,
+        d_head)`` axes in the stored order the head dim picks
+        (:func:`repro.kernels.paged_attention.kv_page_order`): ``(hkv,
+        dh, page)`` for a head dim short of the 128 lanes (minicpm-2b's
+        64), so the 128-token page axis fills them; ``(page, hkv, dh)``
+        for a head dim that fills them itself (starcoder2's 128).  The
+        shape is the stored order, so the pool needs no relayout; the
+        kernel, the reference gather, the scatters, the install and the
+        COW copy all read and write it in that order."""
         return attn.paged_cache_init(cfg, geom.num_pages, geom.page_size)
 
     def copy_page(self, cfg, seg_cache, src, dst):
         return resolve_backend(cfg.decode_backend).paged_copy_page(
-            seg_cache, src, dst
+            seg_cache, src, dst, head_axis=kv_head_axis(cfg.d_head)
         )
 
     def pool_pspecs(self, cfg, *, tp_axis="model", tp_size=1):
-        # stacked pools are (L, num_pages, page, n_kv_heads, d_head): shard
-        # the kv-head axis so each device holds (and streams) 1/tp of every
-        # page; pages themselves never cross devices.  Query heads arrive
-        # pre-partitioned by the column-parallel wq/wk/wv, so only the
-        # post-attention row-parallel wo all-reduces.
+        # stacked pools are (L, num_pages, *block): shard the kv-head axis
+        # (where the stored order puts it) so each device holds (and
+        # streams) 1/tp of every page; pages themselves never cross
+        # devices.  Query heads arrive pre-partitioned by the
+        # column-parallel wq/wk/wv, so only the post-attention
+        # row-parallel wo all-reduces.
         if tp_size > 1 and cfg.n_kv_heads % tp_size == 0:
-            head = P(None, None, None, tp_axis, None)
+            axis = kv_head_axis(cfg.d_head)
+            head = P(*(tp_axis if a == axis else None for a in range(5)))
             return {"k_pages": head, "v_pages": head}
         return {}
 
     def install(self, cfg, dst, src, slot, phys_tok, off_tok):
         return _install_paged(dst, src, phys_tok, off_tok,
-                              {"k": "k_pages", "v": "v_pages"})
+                              {"k": "k_pages", "v": "v_pages"},
+                              kv_page_order(cfg.d_head).index(0))
 
     def src_tokens(self, src):
         return int(src["k"].shape[2])
@@ -230,13 +254,14 @@ class PagedAttnAdapter(CacheAdapter):
     def chunk(self, p, cfg, h, positions, cache, ctx, pos_offset):
         return attn.gqa_paged_prefill_chunk(
             p, cfg, h, positions, cache, ctx["table_row"],
-            ctx["phys_tok"], ctx["off_tok"], pos_offset,
+            ctx["phys_tok"], ctx["off_tok"], pos_offset, ctx["layer"],
         )
 
     def decode(self, p, cfg, h, positions, cache, *, seq_pos, page_table,
-               active):
+               active, layer):
         return attn.gqa_paged_decode(
-            p, cfg, h, positions, cache, page_table, seq_pos, active=active
+            p, cfg, h, positions, cache, page_table, seq_pos, layer,
+            active=active,
         )
 
 
@@ -289,7 +314,7 @@ class RingAttnAdapter(CacheAdapter):
         return out, write_slot_rows(cache, new_row, ctx["slot"])
 
     def decode(self, p, cfg, h, positions, cache, *, seq_pos, page_table,
-               active):
+               active, layer):
         return attn.gqa_ring_decode(
             p, cfg, h, positions, cache, seq_pos, window=cfg.window,
             active=active,
@@ -339,13 +364,14 @@ class LatentMLAAdapter(CacheAdapter):
     def chunk(self, p, cfg, h, positions, cache, ctx, pos_offset):
         return attn.mla_paged_prefill_chunk(
             p, cfg, h, positions, cache, ctx["table_row"],
-            ctx["phys_tok"], ctx["off_tok"], pos_offset,
+            ctx["phys_tok"], ctx["off_tok"], pos_offset, ctx["layer"],
         )
 
     def decode(self, p, cfg, h, positions, cache, *, seq_pos, page_table,
-               active):
+               active, layer):
         return attn.mla_paged_decode(
-            p, cfg, h, positions, cache, page_table, seq_pos, active=active
+            p, cfg, h, positions, cache, page_table, seq_pos, layer,
+            active=active,
         )
 
 
@@ -381,7 +407,7 @@ class SSMStateAdapter(CacheAdapter):
         return out, write_slot_rows(cache, st, ctx["slot"])
 
     def decode(self, p, cfg, h, positions, cache, *, seq_pos, page_table,
-               active):
+               active, layer):
         out, st = ssmm.ssm_forward(p, cfg, h, mode="decode", state=cache)
         if active is not None:
             st = jax.tree.map(
@@ -451,7 +477,7 @@ class CrossAttnAdapter(CacheAdapter):
         return attn.cross_attention(p, cfg, h, rows["k"], rows["v"]), cache
 
     def decode(self, p, cfg, h, positions, cache, *, seq_pos, page_table,
-               active):
+               active, layer):
         # read-only: inactive slots produce garbage that is discarded, and
         # there is no write to mask
         return attn.cross_attention(p, cfg, h, cache["k"], cache["v"]), cache

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.backend import resolve_backend
 from repro.distributed.axes import constrain
+from repro.kernels.paged_attention import kv_page_order
 from repro.models.common import (
     apply_mrope,
     apply_rope,
@@ -152,28 +153,113 @@ def paged_cache_init(cfg: ModelConfig, num_pages: int, page_size: int) -> Dict:
     across layers — a request's page table indexes every layer's pool with
     the same ids.  Page 0 is reserved as the null page (write target for
     inactive slots, gather target for unmapped table entries).
+
+    A page's logical axes ``(page_size, n_kv_heads, d_head)`` are laid out
+    in :func:`~repro.kernels.paged_attention.kv_page_order`, chosen from
+    the head dim so that the page's minor dims fill TPU tiles as stored:
+    ``(page, hkv, dh)`` where ``d_head`` is a multiple of 128, else ``(hkv,
+    dh, page)`` with the page axis on the lanes.  The array's shape is that
+    stored order, so XLA keeps the pool as declared and nothing relayouts
+    it; every reader and writer goes through :func:`write_page_tokens` /
+    :func:`_kv_tokens`, which know the order.
     """
-    dh = cfg.d_head
+    logical = (page_size, cfg.n_kv_heads, cfg.d_head)
+    block = tuple(logical[a] for a in kv_page_order(cfg.d_head))
     return {
-        "k_pages": jnp.zeros((num_pages, page_size, cfg.n_kv_heads, dh), cfg.dtype),
-        "v_pages": jnp.zeros((num_pages, page_size, cfg.n_kv_heads, dh), cfg.dtype),
+        "k_pages": jnp.zeros((num_pages,) + block, cfg.dtype),
+        "v_pages": jnp.zeros((num_pages,) + block, cfg.dtype),
     }
 
 
-def paged_gather_attend(q, k_pages, v_pages, page_table, seq_pos):
+def write_page_tokens(pool, layer, phys, off, x, page_axis: int = 0):
+    """Write runs of consecutive tokens into a layer-stacked page pool.
+
+    ``pool``: (L, num_pages, *block), the token axis at ``page_axis`` of
+    the block.  ``phys``/``off``: (R, C) physical page and in-page offset
+    of each token; each row is a run of C consecutive positions of one
+    sequence (a decode step is B runs of one token, a prefill chunk one
+    run of C).  ``x``: (R, C, *rest), the block's other axes in their
+    stored order; ``layer``: a scalar, or (R,) layers, one per run.  The
+    donated pool is updated where it lies:
+
+    * token axis ahead of the lane axis (``(page, hkv, dh)`` K/V pages,
+      MLA latent pages): each token's row is a contiguous window, and one
+      XLA scatter puts the tokens straight in;
+    * token axis on the lanes (``(hkv, dh, page)`` K/V pages): a token is
+      one lane of every tile of its page, and a scatter of such strips
+      makes XLA relayout the whole pool around it.  So each page a run
+      touches is sliced out, its lanes merged with the run's tokens, and
+      the whole pages scattered back at once (windows of the full block).
+      Tokens routed to the null page keep the real page's old content.
+    """
+    R, C = phys.shape
+    layer = jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (R,))
+    x = x.astype(pool.dtype)
+    if page_axis < pool.ndim - 3:
+        idx = [slice(None)] * (pool.ndim - 2)
+        idx[page_axis] = off
+        return pool.at[(layer[:, None], phys, *idx)].set(x)
+    block = pool.shape[2:]
+    page = block[-1]
+    n = (C + page - 2) // page + 1  # pages a run of C tokens can touch
+    if C > 1:
+        # pad the run so that every lane of every touched page slices a
+        # token (or a pad that lands nowhere)
+        pad = (page, n * page - C)
+        x_pad = jnp.pad(x, ((0, 0), pad) + ((0, 0),) * (x.ndim - 2))
+        phys_pad = jnp.pad(phys, ((0, 0), pad), constant_values=-1)
+    lane = jnp.arange(page)
+    zeros = (0,) * len(block)
+    layers, targets, pages = [], [], []
+    for r in range(R):
+        first = off[r, 0]  # in-page offset of the run's first token
+        tok_page = (first + jnp.arange(C)) // page
+        for k in range(n):
+            # the real page the run's tokens in its k-th page go to
+            target = jnp.max(jnp.where(tok_page == k, phys[r], 0))
+            old = jax.lax.dynamic_slice(
+                pool, (layer[r], target) + zeros, (1, 1) + block)[0, 0]
+            if C == 1:  # a lone token fills lane `first` of its page
+                vals = x[r, 0][..., None]
+                lands = lane == first
+            else:
+                t0 = k * page - first + page  # padded index of lane 0's token
+                vals = jnp.moveaxis(jax.lax.dynamic_slice_in_dim(
+                    x_pad[r], t0, page, axis=0), 0, -1)
+                lands = (jax.lax.dynamic_slice_in_dim(phys_pad[r], t0, page)
+                         == target)
+            layers.append(layer[r])
+            targets.append(target)
+            pages.append(jnp.where(lands, vals, old))
+    return pool.at[jnp.stack(layers), jnp.stack(targets)].set(
+        jnp.stack(pages))
+
+
+def _kv_tokens(pages, d_head: int):
+    """Gathered K/V pages ``(..., *block)`` in logical ``(..., page, hkv,
+    dh)`` order."""
+    n = pages.ndim - 3
+    order = kv_page_order(d_head)
+    return jnp.transpose(pages, (*range(n), *(n + order.index(a)
+                                              for a in range(3))))
+
+
+def paged_gather_attend(q, k_pages, v_pages, page_table, seq_pos, layer):
     """The jnp gather->attend oracle read (the ``"reference"`` backend op).
 
-    Gathers each slot's logical pages back into a dense (B, max_pages*page,
-    Hkv, dh) HBM buffer and runs the same masked one-token attention as the
-    linear cache — keys beyond ``seq_pos`` (tail of a partial page, unmapped
-    null-page entries, stale pages of retired requests) sit at positions
-    above it and mask exactly like empty slots.
+    Gathers each slot's logical pages of layer ``layer`` back into a dense
+    (B, max_pages*page, Hkv, dh) HBM buffer and runs the same masked
+    one-token attention as the linear cache — keys beyond ``seq_pos`` (tail
+    of a partial page, unmapped null-page entries, stale pages of retired
+    requests) sit at positions above it and mask exactly like empty slots.
     """
-    B = q.shape[0]
-    page, hkv, dh = k_pages.shape[1], k_pages.shape[2], k_pages.shape[3]
+    B, dh = q.shape[0], q.shape[-1]
     maxp = page_table.shape[1]
-    kg = k_pages[page_table].reshape(B, maxp * page, hkv, dh)
-    vg = v_pages[page_table].reshape(B, maxp * page, hkv, dh)
+    kg = _kv_tokens(k_pages[layer, page_table], dh)  # (B, maxp, page, ...)
+    vg = _kv_tokens(v_pages[layer, page_table], dh)
+    page, hkv = kg.shape[2], kg.shape[3]
+    kg = kg.reshape(B, maxp * page, hkv, dh)
+    vg = vg.reshape(B, maxp * page, hkv, dh)
     # gathered keys sit at their absolute positions by construction
     k_positions = jnp.broadcast_to(
         jnp.arange(maxp * page, dtype=jnp.int32)[None], (B, maxp * page)
@@ -186,15 +272,18 @@ def gqa_paged_decode(
     cfg: ModelConfig,
     x: jnp.ndarray,  # (B, 1, d) — one token per slot
     positions: jnp.ndarray,  # (B, 1) per-slot absolute positions (RoPE)
-    cache: Dict,  # {"k_pages", "v_pages"} (num_pages, page, Hkv, dh)
+    cache: Dict,  # {"k_pages", "v_pages"} (L, num_pages, *block), stacked
     page_table: jnp.ndarray,  # (B, max_pages) physical page per logical page
     seq_pos: jnp.ndarray,  # (B,) absolute position of the new token
+    layer,  # scalar index of this layer in the stacked pool
     active: Optional[jnp.ndarray] = None,  # (B,) slots actually decoding
 ) -> Tuple[jnp.ndarray, Dict]:
-    """One-token decode against the block-paged cache.
+    """One-token decode against layer ``layer`` of the block-paged cache.
 
     Write: the new K/V lands in page ``page_table[b, pos // page]`` at offset
-    ``pos % page``.  Read: through ``cfg.decode_backend`` — the reference
+    ``pos % page`` of the stacked pool, which the layer scan carries whole:
+    no slice of the pool is cut out or written back.  Read: through
+    ``cfg.decode_backend`` — the reference
     backend gathers each slot's logical pages back into order and runs the
     linear cache's masked attention (:func:`paged_gather_attend`); the
     pallas backend streams the page-table row through the fused kernel
@@ -208,7 +297,8 @@ def gqa_paged_decode(
     B, S, _ = x.shape
     assert S == 1
     q, k, v = _project_qkv(p, cfg, x, positions)
-    page = cache["k_pages"].shape[1]
+    page_axis = kv_page_order(cfg.d_head).index(0)
+    page = cache["k_pages"].shape[2 + page_axis]
     logical = seq_pos // page  # (B,) logical page of the new token
     phys = jnp.take_along_axis(page_table, logical[:, None], axis=1)[:, 0]
     if active is not None:
@@ -216,10 +306,13 @@ def gqa_paged_decode(
     off = seq_pos % page
     # scatter the new token (inactive slots carry page_table rows of 0 and
     # seq_pos 0, so their writes land in the reserved null page)
-    k_pages = cache["k_pages"].at[phys, off].set(k[:, 0])
-    v_pages = cache["v_pages"].at[phys, off].set(v[:, 0])
+    k_pages = write_page_tokens(cache["k_pages"], layer, phys[:, None],
+                                off[:, None], k, page_axis)
+    v_pages = write_page_tokens(cache["v_pages"], layer, phys[:, None],
+                                off[:, None], v, page_axis)
     be = resolve_backend(cfg.decode_backend)
-    out = be.paged_attention_decode(q, k_pages, v_pages, page_table, seq_pos)
+    out = be.paged_attention_decode(q, k_pages, v_pages, page_table, seq_pos,
+                                    layer)
     out = out.reshape(B, 1, cfg.n_heads * cfg.d_head)
     return dense(cfg, out, p["wo"]), {"k_pages": k_pages, "v_pages": v_pages}
 
@@ -233,8 +326,9 @@ def paged_copy_page(cache: Dict, src, dst) -> Dict:
     inside a donating jit, and the slot's page-table entry is swapped to the
     private copy.  Page ids are traced scalars, so every COW event shares
     one compiled shape.  Works on any pool whose leaves are
-    ``(L, num_pages, page, ...)`` — dense/GQA K/V pages and MLA latent pages
-    alike (the page axis is axis 1 after the layer stack).
+    ``(L, num_pages, *block)`` — dense/GQA K/V pages in either stored order
+    and MLA latent pages alike (the page-id axis is axis 1 after the layer
+    stack).
 
     This dense dynamic-slice copy is the ``"reference"`` backend's op; the
     adapters dispatch through ``cfg.decode_backend``, and the pallas
@@ -254,18 +348,20 @@ def gqa_paged_prefill_chunk(
     cfg: ModelConfig,
     x: jnp.ndarray,  # (1, C, d) — one prompt chunk for one slot
     positions: jnp.ndarray,  # (1, C) absolute positions q_off + [0, C)
-    cache: Dict,  # {"k_pages", "v_pages"} (num_pages, page, Hkv, dh)
+    cache: Dict,  # {"k_pages", "v_pages"} (L, num_pages, *block), stacked
     table_row: jnp.ndarray,  # (max_pages,) this slot's page table row
     phys_tok: jnp.ndarray,  # (C,) physical page per chunk token
     off_tok: jnp.ndarray,  # (C,) in-page offset per chunk token
     q_off,  # scalar absolute position of x[:, 0]
+    layer,  # scalar index of this layer in the stacked pool
 ) -> Tuple[jnp.ndarray, Dict]:
     """One prompt chunk against the block-paged cache (prefix-conditioned).
 
     Write first: the chunk's K/V scatters straight into its physical pages
-    (per-token ``(phys, off)`` targets; tokens past the slot's allocation
-    are routed to the null page by the host).  Then gather the slot's whole
-    page table back into logical order — the prefix written by earlier
+    of layer ``layer`` (per-token ``(phys, off)`` targets; tokens past the
+    slot's allocation are routed to the null page by the host).  Then
+    gather the slot's whole page table back into logical order — the
+    prefix written by earlier
     chunks AND this chunk's own keys — and run the same causal masked
     attention as full prefill.  Gathered keys sit at their absolute
     positions, so every unmasked key matches the one-shot prefill's key
@@ -275,12 +371,16 @@ def gqa_paged_prefill_chunk(
     B, C, _ = x.shape
     assert B == 1
     q, k, v = _project_qkv(p, cfg, x, positions)
-    k_pages = cache["k_pages"].at[phys_tok, off_tok].set(k[0])
-    v_pages = cache["v_pages"].at[phys_tok, off_tok].set(v[0])
-    page = k_pages.shape[1]
+    page_axis = kv_page_order(cfg.d_head).index(0)
+    k_pages = write_page_tokens(cache["k_pages"], layer, phys_tok[None],
+                                off_tok[None], k, page_axis)
+    v_pages = write_page_tokens(cache["v_pages"], layer, phys_tok[None],
+                                off_tok[None], v, page_axis)
+    page = k_pages.shape[2 + page_axis]
     maxp = table_row.shape[0]
-    kg = k_pages[table_row].reshape(1, maxp * page, cfg.n_kv_heads, cfg.d_head)
-    vg = v_pages[table_row].reshape(1, maxp * page, cfg.n_kv_heads, cfg.d_head)
+    shape = (1, maxp * page, cfg.n_kv_heads, cfg.d_head)
+    kg = _kv_tokens(k_pages[layer, table_row], cfg.d_head).reshape(shape)
+    vg = _kv_tokens(v_pages[layer, table_row], cfg.d_head).reshape(shape)
     kpos = jnp.arange(maxp * page, dtype=jnp.int32)[None]
     out = chunked_attention(
         q, kg, vg, causal=True, q_offset=q_off, k_positions=kpos,
@@ -471,19 +571,19 @@ def mla_latent_attend(q_lat, q_rope, ckv_c, kr_c, valid, *, scale):
 
 
 def mla_paged_gather_attend(q_lat, q_rope, ckv_pages, krope_pages,
-                            page_table, seq_pos, *, scale):
+                            page_table, seq_pos, layer, *, scale):
     """The jnp gather->attend oracle over latent pages (reference op).
 
-    Gathers each slot's latent pages into logical order and scores with
-    :func:`mla_latent_attend`; gathered entries sit at their absolute
-    positions, so masking by ``k_pos <= seq_pos`` reproduces the linear
-    cache's valid set exactly.
+    Gathers each slot's latent pages of layer ``layer`` into logical order
+    and scores with :func:`mla_latent_attend`; gathered entries sit at
+    their absolute positions, so masking by ``k_pos <= seq_pos`` reproduces
+    the linear cache's valid set exactly.
     """
     B = q_lat.shape[0]
-    page, r_kv = ckv_pages.shape[1], ckv_pages.shape[2]
+    page, r_kv = ckv_pages.shape[2], ckv_pages.shape[3]
     maxp = page_table.shape[1]
-    ckv_g = ckv_pages[page_table].reshape(B, maxp * page, r_kv)
-    kr_g = krope_pages[page_table].reshape(B, maxp * page, -1)
+    ckv_g = ckv_pages[layer, page_table].reshape(B, maxp * page, r_kv)
+    kr_g = krope_pages[layer, page_table].reshape(B, maxp * page, -1)
     k_positions = jnp.arange(maxp * page, dtype=jnp.int32)
     valid = k_positions[None] <= seq_pos[:, None]  # (B, K)
     return mla_latent_attend(q_lat, q_rope, ckv_g, kr_g, valid, scale=scale)
@@ -576,7 +676,9 @@ def mla_paged_cache_init(cfg: ModelConfig, num_pages: int, page_size: int) -> Di
     A page holds ``page_size`` token slots of the MLA *latent* cache — the
     rank-``kv_lora_rank`` c_kv plus the shared ``qk_rope_dim`` rotary key —
     which is all the absorbed-matmul decode ever reads.  Same page-id space
-    and null-page discipline as the dense K/V pool.
+    and null-page discipline as the dense K/V pool.  Pages keep the
+    row-major ``(page, r)`` order: the 512-wide latent fills the lanes as
+    it is.
     """
     return {
         "ckv_pages": jnp.zeros(
@@ -593,14 +695,16 @@ def mla_paged_decode(
     cfg: ModelConfig,
     x: jnp.ndarray,  # (B, 1, d) — one token per slot
     positions: jnp.ndarray,  # (B, 1) per-slot absolute positions (RoPE)
-    cache: Dict,  # {"ckv_pages", "krope_pages"}
+    cache: Dict,  # {"ckv_pages", "krope_pages"} (L, num_pages, page, .)
     page_table: jnp.ndarray,  # (B, max_pages) physical page per logical page
     seq_pos: jnp.ndarray,  # (B,) absolute position of the new token
+    layer,  # scalar index of this layer in the stacked pool
     active: Optional[jnp.ndarray] = None,  # (B,) slots actually decoding
 ) -> Tuple[jnp.ndarray, Dict]:
-    """Absorbed-matmul decode against the latent page pool.
+    """Absorbed-matmul decode against layer ``layer`` of the latent pool.
 
-    Write: the new token's (c_kv, k_rope) lands in its slot's page.  Read:
+    Write: the new token's (c_kv, k_rope) lands in its slot's page of the
+    stacked pool the layer scan carries.  Read:
     through ``cfg.decode_backend``, always in the absorbed formulation —
     q_nope is folded into the latent space through ``W_kv_b`` so attention
     runs over rank-r latents, never materializing per-head K/V.  The
@@ -618,20 +722,22 @@ def mla_paged_decode(
     q_nope, q_rope, ckv, k_rope = _mla_qkv_latent(p, cfg, x, positions)
     wkv_b = p["wkv_b"].reshape(r_kv, H, dn + dv)
 
-    page = cache["ckv_pages"].shape[1]
+    page = cache["ckv_pages"].shape[2]
     logical = seq_pos // page
     phys = jnp.take_along_axis(page_table, logical[:, None], axis=1)[:, 0]
     if active is not None:
         phys = jnp.where(active, phys, 0)  # null page absorbs idle writes
     off = seq_pos % page
-    ckv_pages = cache["ckv_pages"].at[phys, off].set(ckv[:, 0])
-    krope_pages = cache["krope_pages"].at[phys, off].set(k_rope[:, 0])
+    ckv_pages = write_page_tokens(cache["ckv_pages"], layer, phys[:, None],
+                                  off[:, None], ckv)
+    krope_pages = write_page_tokens(cache["krope_pages"], layer,
+                                    phys[:, None], off[:, None], k_rope)
 
     scale = (dn + cfg.qk_rope_dim) ** -0.5
     q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, wkv_b[..., :dn])
     be = resolve_backend(cfg.decode_backend)
     o_lat = be.mla_paged_attention_decode(
-        q_lat, q_rope, ckv_pages, krope_pages, page_table, seq_pos,
+        q_lat, q_rope, ckv_pages, krope_pages, page_table, seq_pos, layer,
         scale=scale,
     )
     out = jnp.einsum("bshr,rhd->bshd", o_lat, wkv_b[..., dn:])  # value expand
@@ -644,11 +750,12 @@ def mla_paged_prefill_chunk(
     cfg: ModelConfig,
     x: jnp.ndarray,  # (1, C, d) — one prompt chunk for one slot
     positions: jnp.ndarray,  # (1, C) absolute positions q_off + [0, C)
-    cache: Dict,  # {"ckv_pages", "krope_pages"}
+    cache: Dict,  # {"ckv_pages", "krope_pages"} (L, num_pages, page, .)
     table_row: jnp.ndarray,  # (max_pages,) this slot's page table row
     phys_tok: jnp.ndarray,  # (C,) physical page per chunk token
     off_tok: jnp.ndarray,  # (C,) in-page offset per chunk token
     q_off,  # scalar absolute position of x[:, 0]
+    layer,  # scalar index of this layer in the stacked pool
 ) -> Tuple[jnp.ndarray, Dict]:
     """One prompt chunk against the latent page pool (prefix-conditioned).
 
@@ -667,13 +774,15 @@ def mla_paged_prefill_chunk(
     q_nope, q_rope, ckv, k_rope = _mla_qkv_latent(p, cfg, x, positions)
     wkv_b = p["wkv_b"].reshape(r_kv, H, dn + dv)
 
-    ckv_pages = cache["ckv_pages"].at[phys_tok, off_tok].set(ckv[0])
-    krope_pages = cache["krope_pages"].at[phys_tok, off_tok].set(k_rope[0])
-    page = ckv_pages.shape[1]
+    ckv_pages = write_page_tokens(cache["ckv_pages"], layer, phys_tok[None],
+                                  off_tok[None], ckv)
+    krope_pages = write_page_tokens(cache["krope_pages"], layer,
+                                    phys_tok[None], off_tok[None], k_rope)
+    page = ckv_pages.shape[2]
     maxp = table_row.shape[0]
     K = maxp * page
-    ckv_g = ckv_pages[table_row].reshape(1, K, r_kv)
-    kr_g = krope_pages[table_row].reshape(1, K, dr)
+    ckv_g = ckv_pages[layer, table_row].reshape(1, K, r_kv)
+    kr_g = krope_pages[layer, table_row].reshape(1, K, dr)
     kpos = jnp.arange(K, dtype=jnp.int32)[None]
     out = _mla_expanded_attend(cfg, wkv_b, q_nope, q_rope, ckv_g, kr_g,
                                pos_offset=q_off, k_positions=kpos)

@@ -66,12 +66,13 @@ def layer_forward(
     page_table=None,  # (B, max_pages) physical page ids (paged KV cache)
     active=None,  # (B,) bool: slots whose decode writes may land
     chunk: Optional[Dict] = None,  # chunked-prefill context (mode "chunk")
+    layer=None,  # index into the segment's stacked paged pools (engine)
 ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
     if chunk is not None or (mode == "decode" and seq_pos is not None):
         return _layer_forward_engine(
             cfg, kind, p, x, positions, mode=mode, cache=cache,
             pos_offset=pos_offset, seq_pos=seq_pos, page_table=page_table,
-            active=active, chunk=chunk,
+            active=active, chunk=chunk, layer=layer,
         )
     aux = jnp.zeros((), jnp.float32)
     new_cache: Dict[str, Any] = {}
@@ -122,7 +123,7 @@ def layer_forward(
 
 def _layer_forward_engine(
     cfg: ModelConfig, kind: str, p: Dict, x, positions, *, mode, cache,
-    pos_offset, seq_pos, page_table, active, chunk,
+    pos_offset, seq_pos, page_table, active, chunk, layer,
 ):
     """Engine-mode layer step (chunked prefill / per-slot paged decode).
 
@@ -130,7 +131,9 @@ def _layer_forward_engine(
     decode gather, active masking — live entirely in the family's
     :class:`~repro.models.adapters.CacheAdapter`; this function only wires
     adapter outputs into the residual stream (attention first, hybrid
-    fusion, cross-attention after the self mixer, then FFN/MoE).
+    fusion, cross-attention after the self mixer, then FFN/MoE).  Paged
+    adapters get their stacked pools and ``layer``; the rest get this
+    layer's rows.
     """
     aux = jnp.zeros((), jnp.float32)
     new_cache: Dict[str, Any] = {}
@@ -138,10 +141,11 @@ def _layer_forward_engine(
 
     def run(ad, sub_p, hh):
         if mode == "chunk":
-            return ad.chunk(sub_p, cfg, hh, positions, cache[ad.key], chunk,
-                            pos_offset)
+            return ad.chunk(sub_p, cfg, hh, positions, cache[ad.key],
+                            dict(chunk, layer=layer), pos_offset)
         return ad.decode(sub_p, cfg, hh, positions, cache[ad.key],
-                         seq_pos=seq_pos, page_table=page_table, active=active)
+                         seq_pos=seq_pos, page_table=page_table, active=active,
+                         layer=layer)
 
     cross = None
     outs = []
@@ -421,7 +425,16 @@ def _run_segments(
     pos_offset=0, remat: bool = False, seq_pos=None, page_table=None,
     active=None, chunk=None,
 ):
-    """Scan each stacked segment; returns (h, new_caches, aux_sum)."""
+    """Scan each stacked segment; returns (h, new_caches, aux_sum).
+
+    In the engine modes a paged adapter's stacked pools ride the scan's
+    carry, with the layer index beside them, instead of its ``xs``/``ys``:
+    each layer scatters its new tokens into the carried pool at ``[layer,
+    phys, off]`` and the decode kernel streams pages of that layer out of
+    it, so no layer's pool is sliced out or written back and the donated
+    pool is updated where it lies.  Non-paged rows (SWA rings, SSM states,
+    cross rows: small per-slot rows) are scanned as ``xs``/``ys``.
+    """
     aux_total = jnp.zeros((), jnp.float32)
     new_caches = {}
     engine = chunk is not None or (mode == "decode" and seq_pos is not None)
@@ -439,30 +452,44 @@ def _run_segments(
             )
         seg_off += n
         cache_seg = caches.get(f"seg{si}") if caches else None
+        pools = {}
+        if engine and cache_seg is not None:
+            paged = {ad.key for ad in A.adapters_for(cfg, kind) if ad.paged}
+            pools = {k: v for k, v in cache_seg.items() if k in paged}
+            cache_seg = {k: v for k, v in cache_seg.items() if k not in paged}
 
         def body(carry, inp, _kind=kind):
-            x = carry
+            x, pools_l, layer = carry
             p_layer = inp[0]
             c_layer = inp[1] if cache_seg is not None else None
+            if pools_l:
+                c_layer = {**c_layer, **pools_l}
             x, c_new, aux = layer_forward(
                 cfg, _kind, p_layer, x, positions,
                 mode=mode, cache=c_layer, pos_offset=pos_offset,
                 seq_pos=seq_pos, page_table=page_table,
-                active=active, chunk=chunk,
+                active=active, chunk=chunk, layer=layer,
             )
             if c_new is None:
                 c_new = 0  # scan needs a consistent pytree; 0 = no cache
-            return x, (c_new, aux)
+            elif pools_l:
+                pools_l = {k: c_new[k] for k in pools_l}
+                c_new = {k: v for k, v in c_new.items() if k not in pools_l}
+            return (x, pools_l, layer + 1), (c_new, aux)
 
         if remat:
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.nothing_saveable
             )
         xs = (stacked, cache_seg) if cache_seg is not None else (stacked,)
-        h, (cache_out, auxs) = jax.lax.scan(body, h, xs)
+        (h, pools, _), (cache_out, auxs) = jax.lax.scan(
+            body, (h, pools, jnp.int32(0)), xs
+        )
         aux_total = aux_total + jnp.sum(auxs)
         if mode in ("prefill", "decode", "chunk"):
-            new_caches[f"seg{si}"] = cache_out
+            new_caches[f"seg{si}"] = (
+                {**cache_out, **pools} if pools else cache_out
+            )
     return h, new_caches, aux_total
 
 

@@ -4,8 +4,10 @@ Kernel-level counterpart of the engine-level backend tests in
 ``tests/test_serve.py``: each fused kernel (interpret mode on CPU — the
 identical grids/BlockSpecs the TPU lowering uses) is swept per page count
 and per ``seq_pos`` edge against the reference gather->attend functions it
-replaces.  Attention parity is gated at 1e-6 (online-softmax reassociation
-— the PR-1 BWMA tolerance); the COW page copy must be bit-exact.
+replaces, reading one layer of a layer-stacked pool whose pages are stored
+in the order the engine stores them (``kv_page_order``).  Attention parity
+is gated at 1e-6 (online-softmax reassociation — the PR-1 BWMA
+tolerance); the COW page copy must be bit-exact.
 """
 import functools
 
@@ -16,6 +18,8 @@ import pytest
 
 from repro.core.backend import resolve_backend
 from repro.kernels.paged_attention import (
+    kv_head_axis,
+    kv_page_order,
     mla_paged_attention_decode,
     paged_attention_decode,
     paged_copy,
@@ -27,14 +31,21 @@ from repro.models.attention import (
 
 PAGE = 8
 MAXP = 4
+LAYERS, LAYER = 3, 1  # stacked pools; the kernels read the middle layer
 TOL = 1e-6
+
+
+def _kv_block(hkv, dh, page=PAGE):
+    """One K/V page's shape in the engine's stored order."""
+    logical = (page, hkv, dh)
+    return tuple(logical[a] for a in kv_page_order(dh))
 
 
 def _table_and_pool(rng, B, maxp, used_pages, leaf_shapes):
     """A paged layout: per-slot table rows mapping ``used_pages`` distinct
     physical pages (page 0 is the null page, never mapped), plus random
-    pool leaves.  Unused table entries point at the null page like the
-    engine's reset rows."""
+    layer-stacked pool leaves ``(LAYERS, num_pages, *shape)``.  Unused
+    table entries point at the null page like the engine's reset rows."""
     num_pages = B * maxp + 1
     table = np.zeros((B, maxp), np.int32)
     phys = rng.permutation(np.arange(1, num_pages))
@@ -43,7 +54,8 @@ def _table_and_pool(rng, B, maxp, used_pages, leaf_shapes):
         table[b, :used_pages] = phys[k:k + used_pages]
         k += used_pages
     pools = [
-        jnp.asarray(rng.standard_normal((num_pages,) + s), jnp.float32)
+        jnp.asarray(rng.standard_normal((LAYERS, num_pages) + s),
+                    jnp.float32)
         for s in leaf_shapes
     ]
     return jnp.asarray(table), pools
@@ -63,18 +75,50 @@ def test_paged_decode_matches_gather(used_pages, groups):
     B, H, dh = 2, 4, 16
     hkv = H // groups
     table, (k_pages, v_pages) = _table_and_pool(
-        rng, B, MAXP, used_pages, [(PAGE, hkv, dh)] * 2
+        rng, B, MAXP, used_pages, [_kv_block(hkv, dh)] * 2
     )
     q = jnp.asarray(rng.standard_normal((B, 1, H, dh)), jnp.float32)
     for pos in _edge_positions(used_pages):
         seq_pos = jnp.full((B,), pos, jnp.int32)
-        ref = paged_gather_attend(q, k_pages, v_pages, table, seq_pos)
+        ref = paged_gather_attend(q, k_pages, v_pages, table, seq_pos, LAYER)
         out = paged_attention_decode(
-            q, k_pages, v_pages, table, seq_pos, interpret=True
+            q, k_pages, v_pages, table, seq_pos, LAYER, interpret=True
         )
         assert out.shape == ref.shape and out.dtype == ref.dtype
         err = float(jnp.max(jnp.abs(out - ref)))
         assert err <= TOL, (used_pages, groups, pos, err)
+
+
+@pytest.mark.parametrize("H,hkv,dh", [(36, 36, 64), (36, 4, 128)],
+                         ids=["minicpm-2b-heads", "starcoder2-heads"])
+def test_paged_decode_head_geometries(H, hkv, dh):
+    """Both benchmark head geometries over the stacked pool: minicpm-2b's
+    (36, 64) pages stored reordered (hkv, dh, page), starcoder2's (4, 128)
+    stored as written (page, hkv, dh).  Each layer of the stack is read
+    through the layer operand, and the oracle reads the same page-table
+    rows out of that layer."""
+    assert kv_page_order(dh) == ((1, 2, 0) if dh == 64 else (0, 1, 2))
+    rng = np.random.default_rng(dh)
+    B = 2
+    table, (k_pages, v_pages) = _table_and_pool(
+        rng, B, MAXP, 3, [_kv_block(hkv, dh)] * 2
+    )
+    q = jnp.asarray(rng.standard_normal((B, 1, H, dh)), jnp.float32)
+    seq_pos = jnp.asarray([2 * PAGE + 3, PAGE - 1], jnp.int32)
+    outs = []
+    for layer in range(LAYERS):
+        ref = paged_gather_attend(q, k_pages, v_pages, table, seq_pos, layer)
+        out = paged_attention_decode(
+            q, k_pages, v_pages, table, seq_pos, jnp.int32(layer),
+            interpret=True,
+        )
+        # reassociation error grows with the dot length: TOL holds the
+        # 16-wide heads of the sweeps above, scaled to these head dims
+        err = float(jnp.max(jnp.abs(out - ref)))
+        assert err <= TOL * dh / 16, (layer, err)
+        outs.append(out)
+    # the layer operand selects: each layer's pages give another answer
+    assert float(jnp.max(jnp.abs(outs[0] - outs[1]))) > 1e-3
 
 
 def test_paged_decode_ragged_positions():
@@ -83,13 +127,13 @@ def test_paged_decode_ragged_positions():
     rng = np.random.default_rng(3)
     B, H, dh = 3, 4, 16
     table, (k_pages, v_pages) = _table_and_pool(
-        rng, B, MAXP, MAXP, [(PAGE, 2, dh)] * 2
+        rng, B, MAXP, MAXP, [_kv_block(2, dh)] * 2
     )
     q = jnp.asarray(rng.standard_normal((B, 1, H, dh)), jnp.float32)
     seq_pos = jnp.asarray([0, PAGE - 1, MAXP * PAGE - 1], jnp.int32)
-    ref = paged_gather_attend(q, k_pages, v_pages, table, seq_pos)
+    ref = paged_gather_attend(q, k_pages, v_pages, table, seq_pos, LAYER)
     out = paged_attention_decode(
-        q, k_pages, v_pages, table, seq_pos, interpret=True
+        q, k_pages, v_pages, table, seq_pos, LAYER, interpret=True
     )
     assert float(jnp.max(jnp.abs(out - ref))) <= TOL
 
@@ -107,11 +151,11 @@ def test_mla_paged_decode_matches_gather(used_pages):
     for pos in _edge_positions(used_pages):
         seq_pos = jnp.full((B,), pos, jnp.int32)
         ref = mla_paged_gather_attend(
-            q_lat, q_rope, ckv_pages, krope_pages, table, seq_pos,
+            q_lat, q_rope, ckv_pages, krope_pages, table, seq_pos, LAYER,
             scale=scale,
         )
         out = mla_paged_attention_decode(
-            q_lat, q_rope, ckv_pages, krope_pages, table, seq_pos,
+            q_lat, q_rope, ckv_pages, krope_pages, table, seq_pos, LAYER,
             scale=scale, interpret=True,
         )
         assert out.shape == ref.shape and out.dtype == ref.dtype
@@ -154,20 +198,22 @@ def test_backend_dispatch_parity():
     rng = np.random.default_rng(11)
     B, H, dh = 2, 4, 16
     table, (k_pages, v_pages) = _table_and_pool(
-        rng, B, MAXP, 2, [(PAGE, 2, dh)] * 2
+        rng, B, MAXP, 2, [_kv_block(2, dh)] * 2
     )
     q = jnp.asarray(rng.standard_normal((B, 1, H, dh)), jnp.float32)
     seq_pos = jnp.asarray([5, 13], jnp.int32)
     ref_be = resolve_backend("reference")
     pal_be = resolve_backend("pallas")  # interpret auto-resolves off-TPU
-    a = ref_be.paged_attention_decode(q, k_pages, v_pages, table, seq_pos)
-    b = pal_be.paged_attention_decode(q, k_pages, v_pages, table, seq_pos)
+    a = ref_be.paged_attention_decode(q, k_pages, v_pages, table, seq_pos,
+                                      LAYER)
+    b = pal_be.paged_attention_decode(q, k_pages, v_pages, table, seq_pos,
+                                      LAYER)
     assert float(jnp.max(jnp.abs(a - b))) <= TOL
-    # layer-stacked pools, page axis 1 — the adapters' COW layout
-    pools = {"k_pages": k_pages[None].repeat(2, 0),
-             "v_pages": v_pages[None].repeat(2, 0)}
-    got = pal_be.paged_copy_page(pools, 1, 2)
-    want = ref_be.paged_copy_page(pools, 1, 2)
+    # the layer-stacked pools themselves, page-id axis 1 — the adapters'
+    # COW layout
+    pools = {"k_pages": k_pages, "v_pages": v_pages}
+    got = pal_be.paged_copy_page(pools, 1, 2, head_axis=kv_head_axis(dh))
+    want = ref_be.paged_copy_page(pools, 1, 2, head_axis=kv_head_axis(dh))
     assert set(got) == set(want)
     for name in got:
         np.testing.assert_array_equal(np.asarray(got[name]),
