@@ -57,8 +57,8 @@ def main(argv=None) -> int:
     for seed in (int(s) for s in args.seeds.split(",")):
         t = time.perf_counter()
         eng, sampler = run.build(spec, mix, seed, cell["chips"])
-        plan = traffic.schedule(mix, args.seconds, seed, spec["vocab_size"],
-                                eng.kv.max_len)
+        plan = traffic.schedule(mix, args.seconds, seed,
+                                MB.family(spec).vocab(spec)[0], eng.kv.max_len)
         w = loop.run(eng, plan, args.seconds, drain_s=run.DRAIN_S)
         peak = run.device_info(jax, cell["chips"])["memory_peak_bytes"]
         run.release(eng)

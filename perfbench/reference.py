@@ -1,15 +1,10 @@
-"""Plain float32 forward pass of the dense decoder family.
+"""Plain float32 reference of a configuration, and its float8 control.
 
-Written from the published descriptions (Llama-style MiniCPM, StarCoder2),
-not from ``repro.models``, and importing nothing of the program:
-
-* RMSNorm (MiniCPM) or LayerNorm with bias (StarCoder2), eps from the file;
-* rotary embeddings on the two halves of each head (``rotate_half``);
-* multi-head or grouped-query attention, causal, within ``sliding_window``;
-* SwiGLU (MiniCPM) or a biased tanh-GELU MLP (StarCoder2);
-* MiniCPM's ``scale_emb``, ``scale_depth / sqrt(L)`` and
-  ``hidden / dim_model_base`` scalings;
-* a head tied to the embedding table or its own.
+The architecture's math (embedding, one layer of each kind, the head) is
+its family module's (``perfbench/families/<family>.py``), written from the
+published description and importing nothing of the program.  This module
+keeps what every family shares: causal attention in blocks of query rows,
+the matmul at each precision, the sampler's noise and the scoring.
 
 Weights come from :mod:`model`'s generator, regenerated from the seed one
 layer at a time, so the reference never holds more than one layer's
@@ -56,30 +51,13 @@ def matmul(a, w, quant):
     return jnp.matmul(a, w, precision=HIGHEST)
 
 
-def norm(spec, x, w, b=None):
-    if MB.is_layernorm(spec):
-        mu = x.mean(-1, keepdims=True)
-        var = ((x - mu) ** 2).mean(-1, keepdims=True)
-        return (x - mu) / jnp.sqrt(var + spec["norm_epsilon"]) * w + b
-    return x / jnp.sqrt((x * x).mean(-1, keepdims=True) + spec["rms_norm_eps"]) * w
-
-
-def rope(x, pos, theta):
-    """x (S, heads, dh); rotate the two halves of each head by pos * freq."""
-    dh = x.shape[-1]
-    inv = 1.0 / theta ** (np.arange(0, dh, 2, dtype=np.float32) / dh)
-    ang = pos[:, None].astype(jnp.float32) * inv[None]  # (S, dh/2)
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., : dh // 2], x[..., dh // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def attention(spec, q, k, v, quant):
-    """Causal (and windowed) attention; q (S, H, dh), k/v (S, Hkv, dh)."""
+def attention(q, k, v, quant, window=None):
+    """Causal attention, within ``window`` positions where given; q (S, H,
+    dh), k/v (S, Hkv, dh)."""
     S, H, dh = q.shape
     rep = H // k.shape[1]
     k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
-    window = spec.get("sliding_window") or S + 1
+    window = window or S + 1
     kpos = jnp.arange(S)
     outs = []
     for q0 in range(0, S, Q_BLOCK):
@@ -98,35 +76,6 @@ def attention(spec, q, k, v, quant):
             vv = v
         outs.append(jnp.einsum("hqk,khd->qhd", p, vv, precision=HIGHEST))
     return jnp.concatenate(outs, 0)
-
-
-def layer(spec, W: Dict, h, quant):
-    """One decoder layer over one sequence h (S, d), float32."""
-    m = MB.dims(spec)
-    f32 = {n: x.astype(jnp.float32) for n, x in W.items()}
-    S = h.shape[0]
-    pos = jnp.arange(S)
-    branch = MB.scalings(spec)["branch"]
-    x = norm(spec, h, f32["input_layernorm.weight"], f32.get("input_layernorm.bias"))
-
-    def proj(name, a):
-        y = matmul(a, f32[f"{name}.weight"], quant)
-        return y + f32[f"{name}.bias"] if f"{name}.bias" in f32 else y
-
-    q = proj("self_attn.q_proj", x).reshape(S, m["H"], m["dh"])
-    k = proj("self_attn.k_proj", x).reshape(S, m["Hkv"], m["dh"])
-    v = proj("self_attn.v_proj", x).reshape(S, m["Hkv"], m["dh"])
-    theta = float(spec["rope_theta"])
-    a = attention(spec, rope(q, pos, theta), rope(k, pos, theta), v, quant)
-    h = h + branch * proj("self_attn.o_proj", a.reshape(S, -1))
-    x = norm(spec, h, f32["post_attention_layernorm.weight"],
-             f32.get("post_attention_layernorm.bias"))
-    if "mlp.gate_proj.weight" in f32:
-        y = proj("mlp.down_proj",
-                 jax.nn.silu(proj("mlp.gate_proj", x)) * proj("mlp.up_proj", x))
-    else:
-        y = proj("mlp.c_proj", jax.nn.gelu(proj("mlp.c_fc", x), approximate=True))
-    return h + branch * y
 
 
 def _gumbel(seed, rid, k, vp: int):
@@ -148,25 +97,27 @@ class Reference:
 
     def __init__(self, spec: Dict, seed: int, quant: str = "f32"):
         self.spec, self.seed, self.quant = spec, seed, quant
-        self.m = MB.dims(spec)
+        fam = MB.family(spec)
+        self.kinds = fam.layer_kinds(spec)
+        self.V, self.Vp = fam.vocab(spec)
         spec_s = json.dumps(spec, sort_keys=True)  # hashable for the jit caches
-        self._layer = _layer_fn(spec_s, quant)
-        self._weights = _weights_fn(spec_s, seed)
+        self._layer = {k: _layer_fn(spec_s, k, quant) for k in set(self.kinds)}
+        self._weights = {k: _weights_fn(spec_s, seed, k) for k in set(self.kinds)}
+        self._embed = _embed_fn(spec_s)
         self._head = _head_fn(spec_s, quant)
         self.g = _globals_fn(spec_s, seed)()
 
     def hidden(self, seqs: Sequence[np.ndarray]) -> List[jnp.ndarray]:
         """Final hidden states (before the final norm) of each sequence."""
-        emb = MB.scalings(self.spec)["emb"]
         hs = []
         for s in seqs:
             n = -(-len(s) // BUCKET) * BUCKET  # causal: padding never leaks back
             toks = np.zeros(n, np.int32)
             toks[: len(s)] = s
-            hs.append(_embed(self.g["embed_tokens.weight"], jnp.asarray(toks), emb))
-        for l in range(self.m["L"]):
-            W = self._weights(l)
-            hs = [self._layer(W, h) for h in hs]
+            hs.append(self._embed(self.g, jnp.asarray(toks)))
+        for l, kind in enumerate(self.kinds):
+            W = self._weights[kind](l)
+            hs = [self._layer[kind](W, h) for h in hs]
         return hs
 
     def logits(self, h, rows):
@@ -192,7 +143,7 @@ class Reference:
         T = float(sampler["temperature"])
         hs = self.hidden(seqs)
         hs_pick = pick.hidden(seqs) if pick is not None else None
-        score = _score_fn(self.m["V"], self.m["Vp"], T)
+        score = _score_fn(self.V, self.Vp, T)
         out = []
         for i, (h, sv) in enumerate(zip(hs, served)):
             toks = np.asarray(sv["tokens"], np.int64)
@@ -204,14 +155,14 @@ class Reference:
                 idx[: len(r)] = r
                 ks = np.arange(b, b + LOGIT_BLOCK, dtype=np.int32)
                 t = np.zeros(LOGIT_BLOCK, np.int32)
-                t[: len(r)] = np.clip(toks[b : b + len(r)], 0, self.m["V"] - 1)
+                t[: len(r)] = np.clip(toks[b : b + len(r)], 0, self.V - 1)
                 zp = None
                 if pick is not None:
                     zp = pick._head(pick.g, hs_pick[i][idx])
                 g = score(self._head(self.g, h[idx]), zp, sampler["seed"],
                           sv["rid"], ks, t)
                 g = np.asarray(g[: len(r)], np.float64)
-                bad = (toks[b : b + len(r)] < 0) | (toks[b : b + len(r)] >= self.m["V"])
+                bad = (toks[b : b + len(r)] < 0) | (toks[b : b + len(r)] >= self.V)
                 g[bad] = np.inf
                 out.append(g)
         return np.concatenate(out) if out else np.zeros(0)
@@ -236,9 +187,10 @@ def _score_fn(V: int, vp: int, T: float):
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_fn(spec_s: str, quant: str):
+def _layer_fn(spec_s: str, kind: str, quant: str):
     spec = json.loads(spec_s)
-    return jax.jit(lambda W, h: layer(spec, W, h, quant))
+    fam = MB.family(spec)
+    return jax.jit(lambda W, h: fam.layer(spec, kind, W, h, quant))
 
 
 @functools.lru_cache(maxsize=None)
@@ -248,25 +200,20 @@ def _globals_fn(spec_s: str, seed: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _weights_fn(spec_s: str, seed: int):
+def _weights_fn(spec_s: str, seed: int, kind: str):
     spec = json.loads(spec_s)
-    return jax.jit(lambda l: MB.layer_weights(spec, seed, l))
+    return jax.jit(lambda l: MB.layer_weights(spec, seed, l, kind))
 
 
-@functools.partial(jax.jit, static_argnums=2)
-def _embed(E, toks, scale):
-    return E[toks].astype(jnp.float32) * scale
+@functools.lru_cache(maxsize=None)
+def _embed_fn(spec_s: str):
+    spec = json.loads(spec_s)
+    fam = MB.family(spec)
+    return jax.jit(lambda g, toks: fam.embed(spec, g, toks))
 
 
 @functools.lru_cache(maxsize=None)
 def _head_fn(spec_s: str, quant: str):
     spec = json.loads(spec_s)
-    div = MB.scalings(spec)["logit_div"]
-
-    def head(g, h):
-        g = {n: x.astype(jnp.float32) for n, x in g.items()}
-        x = norm(spec, h, g["norm.weight"], g.get("norm.bias")) / div
-        w = g["embed_tokens.weight"].T if MB.tied(spec) else g["lm_head.weight"]
-        return matmul(x, w, quant)
-
-    return jax.jit(head)
+    fam = MB.family(spec)
+    return jax.jit(lambda g, h: fam.head(spec, g, h, quant))

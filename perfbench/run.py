@@ -5,7 +5,9 @@
         --seconds 30 --trace 0
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: the
-configuration file, ``perfbench/traffic/<traffic>.json``, the limits of the
+configuration file and its architecture's module
+(``perfbench/families/<family>.py``, :mod:`model`),
+``perfbench/traffic/<traffic>.json``, the limits of the
 check in ``perfbench/limits/<cell>.json``, and, with ``--trace 1``, one
 reader per per-layer metric in ``perfbench/metrics/<metric>.py``.  A metric
 named ``<quantity>.<part>`` (one quantity split by the end-to-end metric it
@@ -50,9 +52,12 @@ T_IMPORT = time.perf_counter()
 TRACE_S = 3.0  # the profiler sub-window of a --trace 1 run
 DRAIN_S = 60.0  # how long the loop waits for the window's last first tokens
 REHEARSAL_DRAIN_S = 5.0
-# the loop's own annotations (Recorder), read back from the trace
+# host spans read back from the trace: the loop's own (Recorder) and the
+# engine's (repro.serve.obs), by which breakdown.idle_gaps names each gap
 HOST_SPANS = ("bench.window", "engine.step", "decode_step", "prefill_chunk",
-              "host_sample")
+              "host_sample", "serve.step", "serve.schedule", "serve.prefill",
+              "serve.decode", "serve.sample", "serve.flush", "serve.sync",
+              "serve.bookkeeping")
 
 
 def fail(msg: str, code: int = 3):
@@ -213,6 +218,9 @@ class Recorder:
 
 def per_layer(rec: Recorder, w, spec, peaks, readers, trace_mod, rehearse):
     """Reduce the trace and run each metric's reader."""
+    import model as MB
+
+    fam = MB.family(spec)
     ex = trace_mod.extract(trace_mod.find_xplane(rec.logdir), HOST_SPANS)
     if rehearse and not ex["devices"]:  # a CPU trace has no device plane
         readers = [(m, mod) for m, mod in readers if m["source"] != "device_trace"]
@@ -225,10 +233,8 @@ def per_layer(rec: Recorder, w, spec, peaks, readers, trace_mod, rehearse):
     print(f"perfbench: trace planes and lines {ex['lines_seen']}; programs "
           f"{trace_mod.top_modules(red)}", file=sys.stderr)
     if not rehearse:
-        import model as MB
-
         try:
-            runs = trace_mod.step_programs(red, MB.dims(spec)["L"])
+            runs = trace_mod.step_programs(red, *fam.decode_kernel(spec))
         except ValueError as e:
             fail(f"the step programs are not what the readers read: {e}")
         seen = {"decode": len(rec.decode_ctx), "prefill": len(rec.chunks)}
@@ -240,7 +246,7 @@ def per_layer(rec: Recorder, w, spec, peaks, readers, trace_mod, rehearse):
             fail(f"the trace holds step program runs {runs} where the loop "
                  f"dispatched {seen}: the engine's calls were fused, renamed "
                  f"or moved, and the readers would misread them")
-    ctx = SimpleNamespace(spec=spec, peaks=peaks, window=w, red=red,
+    ctx = SimpleNamespace(spec=spec, family=fam, peaks=peaks, window=w, red=red,
                           decode_ctx=rec.decode_ctx, chunks=rec.chunks,
                           pages_used=rec.pages_used, snap0=rec.snap0,
                           snap1=rec.snap1, trace=trace_mod)
@@ -252,10 +258,12 @@ def per_layer(rec: Recorder, w, spec, peaks, readers, trace_mod, rehearse):
         metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     breakdown = {"device_ops": trace_mod.top_ops(red),
                  "idle_gaps": trace_mod.idle_gaps(red)}
+    if not rehearse and runs["other_kernels"]:
+        breakdown["other_kernels"] = trace_mod.other_kernels(runs)
     return metrics, breakdown, trace_mod.busy_s(red), red["window_ns"] / 1e9
 
 
-def warm_up(eng, spec, n_slots: int) -> None:
+def warm_up(eng, vocab: int, n_slots: int) -> None:
     """Serve one request per slot, with final prefill chunks of every
     length bucket the engine can emit, so each program the traffic can
     reach (decode step, every chunk shape, the per-slot sampling ops) is
@@ -270,7 +278,7 @@ def warm_up(eng, spec, n_slots: int) -> None:
     lens += [page + 1] * max(0, n_slots - len(lens))
     rng = np.random.default_rng(0)
     for i, n in enumerate(lens):
-        eng.submit(rng.integers(0, spec["vocab_size"], n, dtype=np.int32), 3,
+        eng.submit(rng.integers(0, vocab, n, dtype=np.int32), 3,
                    rid=10**9 + i)
     eng.run()
 
@@ -305,7 +313,7 @@ def build(spec, mix, seed: int, chips: int):
         backend="pallas", temperature=mix["temperature"],
         eos_id=spec["eos_token_id"], seed=sampler["seed"]), mesh=mesh)
     del params
-    warm_up(eng, spec, srv["max_seqs"])
+    warm_up(eng, MB.family(spec).vocab(spec)[0], srv["max_seqs"])
     jax.block_until_ready(eng.kv.data)
     return eng, sampler
 
@@ -359,8 +367,8 @@ def main(argv=None) -> int:
     import traffic
 
     eng, sampler = build(spec, mix, args.seed, chips)
-    plan = traffic.schedule(mix, args.seconds, args.seed, spec["vocab_size"],
-                            eng.kv.max_len)
+    plan = traffic.schedule(mix, args.seconds, args.seed,
+                            MB.family(spec).vocab(spec)[0], eng.kv.max_len)
     readers = metric_readers(bench, cell) if args.trace else []
     rec = None
     logdir = None

@@ -2,25 +2,25 @@
 
 :func:`extract` reads the ``.xplane.pb`` that ``jax.profiler`` writes into a
 plain structure: for each device plane its op line and its module line,
-and the host's annotation spans (the loop's own ``TraceAnnotation``s), as
-``[name, start_ns, duration_ns]`` lists on one clock.  :func:`reduce` works
-on that structure alone, so a small recorded extract
-(``perfbench/testdata/trace_small.json.gz``) checks it without a chip.
+and the host's annotation spans (the engine's ``serve.*`` spans and the
+loop's own ``TraceAnnotation``s), as ``[name, start_ns, duration_ns]``
+lists on one clock.  :func:`reduce` works on that structure alone, so a
+small recorded extract (``perfbench/testdata/trace_named.json.gz``) checks
+it without a chip.
 
-Names, as found in the first chip trace (TPU v5 lite, jax 0.9): the device
-plane ``/device:TPU:0`` has the lines ``XLA Ops`` (one event per HLO op,
-named by the op's whole HLO text; the Pallas kernel is a ``custom-call``
-with ``custom_call_target="tpu_custom_call"`` and no name of its own) and
-``XLA Modules`` (one event per program run, named ``jit_<function>(<id>)``).
-The engine's decode and prefill-chunk steps both show as
-``jit__unknown(<id>)`` (they are jitted ``functools.partial``s), so the
-readers tell them apart by whether the paged-decode kernel runs inside.
-The host's Python thread is the line ``python3``.  The per-metric files
-hold the patterns they match; :func:`step_programs` checks, before any of
-them reads, that the step programs still look so: each decode-step run
-holds exactly one kernel call per layer and each prefill-chunk run none.
-A new kernel in either program (a Pallas prefill, a relayout kernel) stops
-the traced run instead of being counted as the paged-decode kernel.
+Names, as found in chip traces (TPU v5 lite, jax 0.9): the device plane
+``/device:TPU:0`` has the lines ``XLA Ops`` (one event per HLO op, named by
+the op's whole HLO text) and ``XLA Modules`` (one event per program run,
+named ``jit_<function>(<id>)``).  The engine's step programs are
+``jit__paged_step`` (decode) and ``jit_prefill_chunk`` (prefill chunk); a
+Pallas kernel is a ``custom-call`` with
+``custom_call_target="tpu_custom_call"`` whose op is named after the
+kernel (``%paged_attention_decode.13 = ...``).  The host's Python thread
+is the line ``python3``.  :func:`step_programs` checks, before any reader
+reads, that the step programs still look so: each decode-step run holds
+the family's decode kernel once per layer and each prefill-chunk run
+holds it not at all.  Other named kernels in either program are reported,
+not refused: the readers match their own kernel by name.
 """
 from __future__ import annotations
 
@@ -164,29 +164,12 @@ def idle_gaps(red: Dict, n: int = 10) -> List[list]:
     return out
 
 
-def with_op(modules, ops, pattern: str, present: bool = True) -> List[list]:
-    """The module runs inside which an op matching ``pattern`` starts
-    (``present=False``: inside which none does)."""
-    starts = sorted(s for _, s, _ in match(pattern, ops))
-    out = []
-    for m in modules:
-        i = bisect.bisect_left(starts, m[1])
-        hit = i < len(starts) and starts[i] < m[1] + m[2]
-        if hit == present:
-            out.append(m)
-    return out
-
-
-def module_calls(red: Dict, pattern: str, op: Optional[str] = None,
-                 present: bool = True) -> Optional[Tuple[int, float]]:
-    """(calls, device seconds) of the programs whose name matches (and,
-    with ``op``, that run / do not run an op matching it), averaged over
-    devices; None where none ran in the window."""
+def module_calls(red: Dict, pattern: str) -> Optional[Tuple[int, float]]:
+    """(calls, device seconds) of the programs whose name matches,
+    averaged over devices; None where none ran in the window."""
     per = []
     for d in red["devices"].values():
         ev = match(pattern, d["modules"])
-        if op is not None:
-            ev = with_op(ev, d["ops"], op, present)
         if ev:
             per.append((len(ev), sum(e[2] for e in ev)))
     if not per:
@@ -215,30 +198,67 @@ def op_seconds(red: Dict, pattern: str, within: Optional[str] = None
     return sum(tot) / len(tot) / 1e9
 
 
-# the engine's step programs, and the kernel op (see the module's docstring)
-STEP_PROGRAM = r"^jit_(_unknown|_paged_step|prefill_chunk)\("
+# the engine's step programs (see the module's docstring)
+DECODE_PROGRAM = r"^jit__paged_step\("
+PREFILL_PROGRAM = r"^jit_prefill_chunk\("
 KERNEL_OP = r'custom_call_target="tpu_custom_call"'
+OP_NAME = re.compile(r"^%?([\w-]+?)(\.\d+)?(\s|=|$)")
 
 
-def step_programs(red: Dict, layers: int) -> Dict[str, int]:
+def op_name(text: str) -> str:
+    """An op's name without its HLO numbering: ``paged_copy`` of
+    ``%paged_copy.5 = bf16[...] custom-call(...)``."""
+    m = OP_NAME.match(text)
+    return m.group(1) if m else text
+
+
+def kernel_op(name: str) -> str:
+    """The pattern of the ops of the Pallas kernel named ``name``."""
+    return rf"^%?{re.escape(name)}(\.\d+)?\s.*{KERNEL_OP}"
+
+
+def step_programs(red: Dict, kernel: str, calls: int) -> Dict:
     """Whole runs of the decode-step and prefill-chunk programs in the
-    window, per device: {"decode": n, "prefill": n}.  Raises where a step
-    program holds kernel calls other than one per layer, or where the
-    devices disagree."""
-    seen = []
-    for name, d in sorted(red["devices"].items()):
-        starts = sorted(s for _, s, _ in match(KERNEL_OP, d["ops"]))
-        got = {"decode": 0, "prefill": 0}
-        for m in match(STEP_PROGRAM, d["modules"]):
-            k = (bisect.bisect_left(starts, m[1] + m[2])
-                 - bisect.bisect_left(starts, m[1]))
-            if k not in (0, layers):
-                raise ValueError(
-                    f"{name}: a run of {m[0]} holds {k} kernel calls, neither "
-                    f"0 (prefill chunk) nor {layers} (decode step): a kernel "
-                    f"the readers do not know; give it a name of its own")
-            got["decode" if k else "prefill"] += 1
+    window, per device, and the calls of other kernels inside them:
+    ``{"decode": n, "prefill": n, "other_kernels": {name: calls},
+    "other_kernel_s": {name: seconds}}``, the seconds averaged over devices.
+    Raises where a decode run holds other than ``calls`` calls of the
+    decode kernel ``kernel``, where a prefill run holds any, or where the
+    devices disagree on the runs or calls."""
+    ours = re.compile(kernel_op(kernel))
+    seen, other_ns = [], {}
+    for dev, d in sorted(red["devices"].items()):
+        kernels = sorted((s, name, dur) for name, s, dur in match(KERNEL_OP, d["ops"]))
+        starts = [s for s, _, _ in kernels]
+        got = {"decode": 0, "prefill": 0, "other_kernels": {}}
+        for part, pattern, want in (("decode", DECODE_PROGRAM, calls),
+                                    ("prefill", PREFILL_PROGRAM, 0)):
+            for m in match(pattern, d["modules"]):
+                inside = kernels[bisect.bisect_left(starts, m[1]):
+                                 bisect.bisect_left(starts, m[1] + m[2])]
+                k = sum(1 for _, name, _ in inside if ours.search(name))
+                if k != want:
+                    raise ValueError(
+                        f"{dev}: a run of {m[0]} holds {k} calls of the "
+                        f"decode kernel {kernel!r}, not {want}: the readers "
+                        f"would misread the step")
+                other = got["other_kernels"]
+                for _, name, dur in inside:
+                    if not ours.search(name):
+                        other[op_name(name)] = other.get(op_name(name), 0) + 1
+                        other_ns[op_name(name)] = other_ns.get(op_name(name), 0) + dur
+                got[part] += 1
         seen.append(got)
     if any(g != seen[0] for g in seen):
         raise ValueError(f"the devices ran different step programs: {seen}")
-    return seen[0]
+    return {**seen[0], "other_kernel_s": {
+        name: ns / len(seen) / 1e9 for name, ns in other_ns.items()}}
+
+
+def other_kernels(runs: Dict, n: int = 10) -> List[list]:
+    """The result's ``breakdown.other_kernels``: the kernels other than the
+    family's decode kernel that ran inside the step programs, with their
+    device seconds, most first. No reader counts their work, so a share of
+    the decode kernel's roofline leaves them out."""
+    return [[name, s] for name, s in
+            sorted(runs["other_kernel_s"].items(), key=lambda x: -x[1])[:n]]
