@@ -47,7 +47,7 @@ def run(scale: float = 1.0, backend: str = "reference"):
 
     # paged-decode structural numbers: per grid step the fused decode kernel
     # fetches exactly one physical K/V page — a trailing-dims-contiguous
-    # (1, page, hkv, dh) tile, one DMA descriptor — while the reference path
+    # (1, hkv, page, dh) tile, one DMA descriptor — while the reference path
     # first materializes every slot's gathered history in HBM
     # (max_pages * page tokens per slot, K and V).  Serving-shaped numbers
     # (page=128 tokens, 8 KV heads, d_head=128, bf16):

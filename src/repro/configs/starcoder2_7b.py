@@ -1,7 +1,7 @@
 """StarCoder2-7B — dense code model. [arXiv:2402.19173; hf]
 
 32L, d_model 4608, 36 heads (GQA kv=4), d_ff 18432, vocab 49152, LayerNorm +
-GELU, RoPE, attention bias.
+GELU, RoPE (theta 1e6, as published), attention bias.
 """
 from repro.configs.base import ModelConfig
 
@@ -13,7 +13,7 @@ def full_config() -> ModelConfig:
         name=ARCH_ID, family="dense",
         n_layers=32, d_model=4608, n_heads=36, n_kv_heads=4, d_head=128,
         d_ff=18432, vocab_size=49152,
-        norm="layernorm", act="gelu", qkv_bias=True, rope_theta=1e5,
+        norm="layernorm", act="gelu", qkv_bias=True, rope_theta=1e6,
     )
 
 

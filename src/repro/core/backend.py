@@ -54,7 +54,7 @@ from repro.kernels.bwma_layernorm import bwma_layernorm
 from repro.kernels.bwma_softmax import bwma_softmax
 from repro.kernels.bwma_transpose import bwma_transpose
 from repro.kernels.paged_attention import (
-    kv_head_axis,
+    KV_HEAD_AXIS,
     mla_paged_attention_decode,
     paged_attention_decode,
     paged_copy,
@@ -279,12 +279,11 @@ class PallasBackend(_ElementwiseMixin):
 
     def paged_attention_decode(self, q, k_pages, v_pages, page_table,
                                seq_pos, layer):
-        axis = kv_head_axis(q.shape[-1])
-        pol = self._tp_policy(q.shape[2], k_pages.shape[axis])
+        pol = self._tp_policy(q.shape[2], k_pages.shape[KV_HEAD_AXIS])
         if pol is not None:
             tp = pol.tp_axis
             head = _P(None, None, tp, None)
-            pool = self._axis_spec(k_pages.ndim, axis, tp)
+            pool = self._axis_spec(k_pages.ndim, KV_HEAD_AXIS, tp)
             return jax.shard_map(
                 self._paged_attention_decode, mesh=pol.mesh,
                 in_specs=(head, pool, pool, _P(), _P(), _P()),

@@ -29,15 +29,19 @@ decode kernels take the stacked pool plus the layer index as a
 scalar-prefetch operand, so no per-layer slice of the pool is ever cut out
 or written back.  A dense/GQA K/V page holds the logical axes ``(page,
 n_kv_heads, d_head)`` in the order :func:`kv_page_order` picks from the
-head dim, so that the page's minor dims fill TPU tiles as stored: a head
-dim that fills the 128 lanes keeps ``(page, hkv, dh)`` (starcoder2's
-``(128, 4, 128)``); any other head dim gives the lanes to the page axis,
-which the engine sizes to the kernel's 128-token tile, and stores ``(hkv,
-dh, page)`` (minicpm-2b's ``(36, 64, 128)``; row-major ``(128, 36, 64)``
-would have XLA relayout the pool around every kernel call).  The kernel
-reads ``(hkv, dh, page)`` blocks straight into the MXU, with no in-VMEM
-transpose.  MLA latent pages keep ``(page, r)``: 512 and 64 lanes are
-already aligned blocks.
+head dim: the KV-head axis leads, and the page's two minor dims are the
+page axis and the head dim, which fill whole TPU tiles as stored for any
+number of KV heads, so also for the one head a tensor-parallel shard may
+hold.  A head dim that fills the 128 lanes stores ``(hkv, page, dh)``
+(starcoder2's ``(4, 128, 128)``, ``(1, 128, 128)`` a shard at TP=4); any
+other gives the lanes to the page axis, which the engine sizes to the
+kernel's 128-token tile, and stores ``(hkv, dh, page)`` (minicpm-2b's
+``(36, 64, 128)``).  Row-major ``(page, hkv, dh)`` would put the heads on
+the sublanes, where one head leaves them empty and XLA stores the pool in
+another order and relayouts it around every kernel call.  The kernel reads
+either order's blocks straight into the MXU, with no in-VMEM transpose.
+MLA latent pages keep ``(page, r)``: 512 and 64 lanes are already aligned
+blocks.
 
 Three kernels:
 
@@ -84,25 +88,25 @@ LANES = 128
 def kv_page_order(d_head: int) -> tuple:
     """Stored order of a K/V page's logical ``(page, hkv, dh)`` axes.
 
-    The minor dim of a stored page should be a multiple of the 128 lanes
-    (and the second-minor one fill the sublanes), else XLA's TPU layout
-    assignment stores the pool in another order and relayouts it around
-    every kernel call.  A head dim that fills the lanes keeps the row-major
-    ``(page, hkv, dh)``; any other gives the lanes to the page axis, which
-    the engine sizes to the kernel's 128-token tile, with ``dh`` on the
-    sublanes: ``(hkv, dh, page)``.  For minicpm-2b's ``(128, 36, 64)`` and
-    starcoder2's ``(128, 4, 128)`` pages this is the order
-    :func:`_tiled_order` picks; it is fixed by the head dim alone so that
-    every reader (this kernel, the reference gather, the tensor-parallel
-    specs) can tell a pool's order from the head dim.
+    The two minor dims of a stored page should fill TPU tiles (the minor
+    one a multiple of the 128 lanes, the second-minor one the sublanes),
+    else XLA's TPU layout assignment stores the pool in another order and
+    relayouts it around every kernel call.  The KV-head axis leads, so the
+    minor pair is the page axis and the head dim whatever the number of KV
+    heads a device holds (tensor parallelism shards the head axis, down to one
+    head a shard): ``(hkv, page, dh)`` for a head dim that fills the
+    lanes, and for any other the page axis, which the engine sizes to the
+    kernel's 128-token tile, on the lanes with ``dh`` on the sublanes:
+    ``(hkv, dh, page)``.  It is fixed by the head dim alone so that every
+    reader (this kernel, the reference gather, the tensor-parallel specs)
+    can tell a pool's order from the head dim.
     """
-    return (0, 1, 2) if d_head % LANES == 0 else (1, 2, 0)
+    return (1, 0, 2) if d_head % LANES == 0 else (1, 2, 0)
 
 
-def kv_head_axis(d_head: int) -> int:
-    """The kv-head axis of a layer-stacked ``(L, num_pages, *block)`` K/V
-    pool: the axis tensor parallelism shards."""
-    return 2 + kv_page_order(d_head).index(1)
+# the kv-head axis of a layer-stacked ``(L, num_pages, *block)`` K/V pool,
+# the axis tensor parallelism shards: the heads lead a page in either order
+KV_HEAD_AXIS = 2
 
 
 # --------------------------------------------------------------------------
@@ -122,19 +126,15 @@ def _gqa_decode_kernel(table_ref, seqpos_ref, layer_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0].astype(jnp.float32)        # (H, dh)
-    k = k_ref[...].astype(jnp.float32)      # one stored page
-    v = v_ref[...].astype(jnp.float32)
+    k = k_ref[...].astype(jnp.float32)      # one stored page: (Hkv, page, dh)
+    v = v_ref[...].astype(jnp.float32)      # or (Hkv, dh, page)
     H, dh = q.shape
     hkv = H // groups
     qg = q.reshape(hkv, groups, dh)
-    if not page_minor:
-        # (page, Hkv, dh) pages: bring K to (Hkv, dh, page), V to
-        # (Hkv, page, dh) for the batched matmuls below
-        k = k.transpose(1, 2, 0)
-        v = v.transpose(1, 0, 2)
-    # per-page grouped scores: (Hkv, g, dh) x (Hkv, dh, page) -> (Hkv, g, page)
+    # per-page grouped scores: (Hkv, g, dh) x K over its dh axis
+    # -> (Hkv, g, page)
     s = jax.lax.dot_general(
-        qg, k, (((2,), (1,)), ((0,), (0,))),
+        qg, k, (((2,), (1 if page_minor else 2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     ) * scale
     # this page covers absolute positions [j*page, (j+1)*page); mask beyond
@@ -186,7 +186,7 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_pos, layer,
     assert S == 1
     order = kv_page_order(dh)
     block = k_pages.shape[2:]
-    page, hkv = block[order.index(0)], block[order.index(1)]
+    hkv, page = block[0], block[order.index(0)]
     maxp = page_table.shape[1]
     scale = dh ** -0.5 if scale is None else scale
     # ONE physical page of one layer per grid step, straight from the table

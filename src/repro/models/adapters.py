@@ -32,7 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core.backend import resolve_backend
-from repro.kernels.paged_attention import kv_head_axis, kv_page_order
+from repro.kernels.paged_attention import KV_HEAD_AXIS, kv_page_order
 from repro.models import attention as attn
 from repro.models import ssm as ssmm
 
@@ -218,28 +218,29 @@ class PagedAttnAdapter(CacheAdapter):
         d_head)`` axes in the stored order the head dim picks
         (:func:`repro.kernels.paged_attention.kv_page_order`): ``(hkv,
         dh, page)`` for a head dim short of the 128 lanes (minicpm-2b's
-        64), so the 128-token page axis fills them; ``(page, hkv, dh)``
+        64), so the 128-token page axis fills them; ``(hkv, page, dh)``
         for a head dim that fills them itself (starcoder2's 128).  The
-        shape is the stored order, so the pool needs no relayout; the
-        kernel, the reference gather, the scatters, the install and the
-        COW copy all read and write it in that order."""
+        heads lead, so a tensor-parallel shard of any number of them, one
+        included, holds whole tiles.  The shape is the stored order, so
+        the pool needs no relayout; the kernel, the reference gather, the
+        scatters, the install and the COW copy all read and write it in
+        that order."""
         return attn.paged_cache_init(cfg, geom.num_pages, geom.page_size)
 
     def copy_page(self, cfg, seg_cache, src, dst):
         return resolve_backend(cfg.decode_backend).paged_copy_page(
-            seg_cache, src, dst, head_axis=kv_head_axis(cfg.d_head)
+            seg_cache, src, dst, head_axis=KV_HEAD_AXIS
         )
 
     def pool_pspecs(self, cfg, *, tp_axis="model", tp_size=1):
         # stacked pools are (L, num_pages, *block): shard the kv-head axis
-        # (where the stored order puts it) so each device holds (and
-        # streams) 1/tp of every page; pages themselves never cross
-        # devices.  Query heads arrive pre-partitioned by the
-        # column-parallel wq/wk/wv, so only the post-attention
-        # row-parallel wo all-reduces.
+        # (which leads each page) so each device holds (and streams) 1/tp
+        # of every page; pages themselves never cross devices.  Query
+        # heads arrive pre-partitioned by the column-parallel wq/wk/wv, so
+        # only the post-attention row-parallel wo all-reduces.
         if tp_size > 1 and cfg.n_kv_heads % tp_size == 0:
-            axis = kv_head_axis(cfg.d_head)
-            head = P(*(tp_axis if a == axis else None for a in range(5)))
+            head = P(*(tp_axis if a == KV_HEAD_AXIS else None
+                       for a in range(5)))
             return {"k_pages": head, "v_pages": head}
         return {}
 

@@ -156,12 +156,13 @@ def paged_cache_init(cfg: ModelConfig, num_pages: int, page_size: int) -> Dict:
 
     A page's logical axes ``(page_size, n_kv_heads, d_head)`` are laid out
     in :func:`~repro.kernels.paged_attention.kv_page_order`, chosen from
-    the head dim so that the page's minor dims fill TPU tiles as stored:
-    ``(page, hkv, dh)`` where ``d_head`` is a multiple of 128, else ``(hkv,
-    dh, page)`` with the page axis on the lanes.  The array's shape is that
-    stored order, so XLA keeps the pool as declared and nothing relayouts
-    it; every reader and writer goes through :func:`write_page_tokens` /
-    :func:`_kv_tokens`, which know the order.
+    the head dim so that the page's minor dims fill TPU tiles as stored,
+    for any number of KV heads a device holds: ``(hkv, page, dh)`` where
+    ``d_head`` is a multiple of 128, else ``(hkv, dh, page)`` with the page
+    axis on the lanes.  The array's shape is that stored order, so XLA
+    keeps the pool as declared and nothing relayouts it; every reader and
+    writer goes through :func:`write_page_tokens` / :func:`_kv_tokens`,
+    which know the order.
     """
     logical = (page_size, cfg.n_kv_heads, cfg.d_head)
     block = tuple(logical[a] for a in kv_page_order(cfg.d_head))
@@ -182,9 +183,12 @@ def write_page_tokens(pool, layer, phys, off, x, page_axis: int = 0):
     stored order; ``layer``: a scalar, or (R,) layers, one per run.  The
     donated pool is updated where it lies:
 
-    * token axis ahead of the lane axis (``(page, hkv, dh)`` K/V pages,
-      MLA latent pages): each token's row is a contiguous window, and one
-      XLA scatter puts the tokens straight in;
+    * token axis ahead of the lane axis (``(hkv, page, dh)`` K/V pages,
+      MLA latent ``(page, r)`` pages): each token is one lane row of each
+      head's tile, and one XLA scatter puts the rows straight in, indexed
+      by head as well, so that each update is a single row (a window that
+      spans the heads would have XLA put the heads on the sublanes and
+      relayout the pool around every kernel call);
     * token axis on the lanes (``(hkv, dh, page)`` K/V pages): a token is
       one lane of every tile of its page, and a scatter of such strips
       makes XLA relayout the whole pool around it.  So each page a run
@@ -195,10 +199,12 @@ def write_page_tokens(pool, layer, phys, off, x, page_axis: int = 0):
     R, C = phys.shape
     layer = jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (R,))
     x = x.astype(pool.dtype)
+    if page_axis == 0:
+        return pool.at[layer[:, None], phys, off].set(x)
     if page_axis < pool.ndim - 3:
-        idx = [slice(None)] * (pool.ndim - 2)
-        idx[page_axis] = off
-        return pool.at[(layer[:, None], phys, *idx)].set(x)
+        heads = jnp.arange(pool.shape[2])
+        return pool.at[layer[:, None, None], phys[:, :, None], heads,
+                       off[:, :, None]].set(x)
     block = pool.shape[2:]
     page = block[-1]
     n = (C + page - 2) // page + 1  # pages a run of C tokens can touch

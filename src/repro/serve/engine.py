@@ -446,6 +446,8 @@ class Engine:
             prefix_sharing=sharing,
         ), mesh=mesh)
         self.obs = Observability(deep=ec.obs, max_seqs=ec.max_seqs)
+        self.obs.deployment(1 if mesh is None else mesh.devices.size,
+                            self.kv.cache_bytes_per_device())
         self.sched = Scheduler(self.kv, ec.max_seqs, obs=self.obs)
         self.chunk_size = self._resolve_chunk(ec.prefill_chunk)
         if ec.prefill_tokens_per_step < 0:

@@ -425,6 +425,8 @@ class Observability:
             ("jit_decode_traces", "compiled entries of the paged decode step"),
             ("jit_prefill_chunk_traces", "compiled entries of the chunk step"),
             ("jit_prefill_traces", "compiled entries of the one-shot prefill"),
+            ("mesh_devices", "devices the engine's mesh spans (1 without one)"),
+            ("kv_pool_bytes_per_device", "KV page-pool bytes held on one device"),
         ):
             r.gauge(name, help)
         occ_edges = tuple(range(max_seqs + 1)) if max_seqs else _OCCUPANCY_EDGES_DEFAULT
@@ -468,6 +470,14 @@ class Observability:
     def sync(self, n: int = 1) -> _SyncSpan:  # repro: hot-loop
         """``serve.sync`` around a blocking transfer of ``n`` device arrays."""
         return _SyncSpan(self, n)
+
+    # -- deployment (set once, at engine construction) -------------------------
+
+    def deployment(self, mesh_devices: int, pool_bytes_per_device: int) -> None:
+        """The devices the engine spans and the KV pool each one holds."""
+        r = self.registry
+        r.gauge("mesh_devices").set(mesh_devices)
+        r.gauge("kv_pool_bytes_per_device").set(pool_bytes_per_device)
 
     # -- request lifecycle events (called by the scheduler) ------------------
 

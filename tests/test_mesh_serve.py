@@ -36,6 +36,16 @@ def _dense_cfg(**over):
     return dataclasses.replace(cfg, n_heads=8, n_kv_heads=8, **over)
 
 
+def _starcoder2_cfg(**over):
+    """starcoder2 (LayerNorm, biases, tanh-GELU, untied head) with 4 KV
+    heads of 128: on a 4-way model axis each device holds one, in the
+    (hkv, page, dh) page order that lane-filling heads are stored in."""
+    cfg = C.get_config("starcoder2-7b", smoke=True, dtype=jnp.float32)
+    over = {"block": 8, **over}
+    return dataclasses.replace(cfg, n_heads=8, n_kv_heads=4, d_head=128,
+                               **over)
+
+
 def _moe_cfg(**over):
     """granite MoE, GQA heads lifted to divide a 4-way model axis."""
     cfg = C.get_config("granite-moe-3b-a800m", smoke=True, dtype=jnp.float32)
@@ -89,6 +99,26 @@ def test_mesh_parity_dense_chunked_prefill(backend):
         EngineConfig(max_seqs=2, max_len=32, page_size=8, backend=backend),
         mesh,
     )
+    assert eng.kv.cache_bytes_per_device() == eng.kv.cache_bytes() // 4
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_mesh_parity_one_kv_head_per_device(backend):
+    """starcoder2's block with one KV head per device (TP=4 over 4 KV
+    heads, as the four-chip benchmark cell serves it): chunked prefill and
+    decode stay bit-identical to one device under both backends, and each
+    device holds one head's pages."""
+    cfg = _starcoder2_cfg(decode_backend=backend)
+    assert not cfg.tie_embeddings and cfg.qkv_bias
+    eng = _assert_mesh_parity(
+        cfg, _prompts(cfg, (12, 9, 14)), 8,
+        EngineConfig(max_seqs=2, max_len=32, page_size=8, backend=backend),
+        make_serve_mesh("1x4"),
+    )
+    assert eng.prefill_chunks > 3
+    pool = eng.kv.data["seg0"]["attn"]["k_pages"]
+    assert pool.shape[2:] == (4, 8, 128)
+    assert pool.addressable_shards[0].data.shape[2:] == (1, 8, 128)
     assert eng.kv.cache_bytes_per_device() == eng.kv.cache_bytes() // 4
 
 

@@ -18,7 +18,7 @@ import pytest
 
 from repro.core.backend import resolve_backend
 from repro.kernels.paged_attention import (
-    kv_head_axis,
+    KV_HEAD_AXIS,
     kv_page_order,
     mla_paged_attention_decode,
     paged_attention_decode,
@@ -89,15 +89,18 @@ def test_paged_decode_matches_gather(used_pages, groups):
         assert err <= TOL, (used_pages, groups, pos, err)
 
 
-@pytest.mark.parametrize("H,hkv,dh", [(36, 36, 64), (36, 4, 128)],
-                         ids=["minicpm-2b-heads", "starcoder2-heads"])
+@pytest.mark.parametrize("H,hkv,dh", [(36, 36, 64), (36, 4, 128),
+                                      (9, 1, 128)],
+                         ids=["minicpm-2b-heads", "starcoder2-heads",
+                              "starcoder2-tp4-shard-heads"])
 def test_paged_decode_head_geometries(H, hkv, dh):
-    """Both benchmark head geometries over the stacked pool: minicpm-2b's
-    (36, 64) pages stored reordered (hkv, dh, page), starcoder2's (4, 128)
-    stored as written (page, hkv, dh).  Each layer of the stack is read
-    through the layer operand, and the oracle reads the same page-table
-    rows out of that layer."""
-    assert kv_page_order(dh) == ((1, 2, 0) if dh == 64 else (0, 1, 2))
+    """The benchmark head geometries over the stacked pool: minicpm-2b's
+    (36, 64) pages stored (hkv, dh, page), starcoder2's (4, 128) stored
+    (hkv, page, dh), and the one KV head of 128 (with 9 query heads) that
+    each device holds of starcoder2 at TP=4.  Each layer of the stack is
+    read through the layer operand, and the oracle reads the same
+    page-table rows out of that layer."""
+    assert kv_page_order(dh) == ((1, 2, 0) if dh == 64 else (1, 0, 2))
     rng = np.random.default_rng(dh)
     B = 2
     table, (k_pages, v_pages) = _table_and_pool(
@@ -212,8 +215,8 @@ def test_backend_dispatch_parity():
     # the layer-stacked pools themselves, page-id axis 1 — the adapters'
     # COW layout
     pools = {"k_pages": k_pages, "v_pages": v_pages}
-    got = pal_be.paged_copy_page(pools, 1, 2, head_axis=kv_head_axis(dh))
-    want = ref_be.paged_copy_page(pools, 1, 2, head_axis=kv_head_axis(dh))
+    got = pal_be.paged_copy_page(pools, 1, 2, head_axis=KV_HEAD_AXIS)
+    want = ref_be.paged_copy_page(pools, 1, 2, head_axis=KV_HEAD_AXIS)
     assert set(got) == set(want)
     for name in got:
         np.testing.assert_array_equal(np.asarray(got[name]),

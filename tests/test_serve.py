@@ -1372,7 +1372,7 @@ def test_pallas_and_reference_engines_agree_through_mixed_run(d_head):
     recompute and a copy-on-write page copy gives bit-identical greedy
     tokens on the reference and pallas backends, for both stored page
     orders: a head dim short of the 128 lanes stores pages (hkv, dh,
-    page), one that fills them (page, hkv, dh).  Both backends read and
+    page), one that fills them (hkv, page, dh).  Both backends read and
     write the same layer-stacked pools carried through the layer scan."""
     cfg = _paged_cfg(block=8, d_head=d_head)
     params = M.init_params(cfg, jax.random.PRNGKey(0))

@@ -12,7 +12,7 @@ import repro.configs as C
 from repro.configs.shapes import cache_specs
 from repro.distributed import sharding as SH
 from repro.distributed.axes import abstract_mesh
-from repro.kernels.paged_attention import kv_head_axis
+from repro.kernels.paged_attention import KV_HEAD_AXIS
 from repro.models import model as M
 
 MESHES = {
@@ -120,13 +120,13 @@ def test_paged_cache_pool_specs_valid(arch, tp):
     specs = SH.paged_cache_pspecs(cfg, mesh, pools)
     _check_spec_tree(mesh, pools, specs)
     # when the kv-head axis divides, paged K/V pools must actually shard,
-    # on the kv-head axis wherever the stored page order puts it
+    # on the kv-head axis, which leads each page in either stored order
     if cfg.n_kv_heads and cfg.n_kv_heads % tp == 0 and any(
         isinstance(ad, A.PagedAttnAdapter) for ad in A.all_adapters(cfg)
     ):
         flat = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
         assert any("model" in tuple(s) for s in flat), specs
-        axis = kv_head_axis(cfg.d_head)
+        axis = KV_HEAD_AXIS
         for si in range(len(M.layer_segments(cfg))):
             seg_pools, seg_specs = pools[f"seg{si}"], specs[f"seg{si}"]
             if "k_pages" not in seg_pools.get("attn", {}):

@@ -15,11 +15,14 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 import repro.configs as C
 from repro.core import backend as BE
+from repro.distributed import axes as AX
+from repro.distributed import sharding as SH
 from repro.kernels.bwma_gemm import bwma_gemm
 from repro.kernels.paged_attention import (
     mla_paged_attention_decode,
@@ -34,7 +37,7 @@ NUM_PAGES = SLOTS * MAX_PAGES + 1     # plus the null page
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     from jax.experimental import topologies
 
     try:
@@ -47,8 +50,13 @@ def one_chip():
     # compilation cache, so keep these compiles out of it
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _compile(fn, one_chip, *shapes, donate=(), kernel=""):
@@ -129,62 +137,96 @@ def test_bwma_gemm_bert_base(one_chip):
     )
 
 
-# the two benchmark cells' engines: (config, slots, pool pages, max_len)
+# the benchmark cells' engines: (config, cut, slots, pool pages, max_len,
+# devices on the model axis of a 1xN mesh)
 CELLS = {
-    "minicpm-2b": ("minicpm-2b", {}, 12, 65, 2048),
-    "starcoder2-7b-pp2": ("starcoder2-7b", {"n_layers": 16}, 16, 513, 4096),
+    "minicpm-2b": ("minicpm-2b", {}, 12, 65, 2048, 1),
+    "starcoder2-7b-pp2": ("starcoder2-7b", {"n_layers": 16}, 16, 513, 4096, 1),
+    "starcoder2-7b-tp4": ("starcoder2-7b", {}, 16, 513, 4096, 4),
 }
 _POOL_OPS = ("copy", "dynamic-slice", "dynamic-update-slice")
+_COLLECTIVE = re.compile(r"\s*(?:ROOT )?%\S+ = (.*?) (all-reduce|all-gather|"
+                         r"reduce-scatter|all-to-all|collective-permute)"
+                         r"(?:-start)?\(")
+_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]")
+
+
+def _nbytes(dtype: str, dims: str) -> int:
+    """Bytes of an HLO array type (``bf16``, ``f32``, ``s32``, ``pred``)."""
+    n = max(1, int(re.sub(r"\D", "", dtype) or 8) // 8)
+    for d in filter(None, dims.split(",")):
+        n *= int(d)
+    return n
 
 
 @pytest.mark.parametrize("cell", list(CELLS))
 @pytest.mark.parametrize("step", ["decode_step", "prefill_chunk"])
-def test_engine_step_keeps_pool_in_place(one_chip, monkeypatch, cell, step):
+def test_engine_step_keeps_pool_in_place(v5e_2x2, monkeypatch, cell, step):
     """The decode and prefill-chunk programs at each benchmark cell's
     geometry (bf16, 128-token pages) move no pool-sized buffer: the donated
     pool is aliased, the program's temp is under a twentieth of the pool
     (the layer scan carries the stacked pool and writes tokens into it
     where it lies), and no copy, dynamic-slice or dynamic-update-slice
-    yields the stacked pool or one layer of it."""
+    yields the stacked pool or one layer of it.  On the tensor-parallel
+    cell's 1x4 mesh (``SH.serve_shardings``, each device one of the 4 KV
+    heads) the same holds per device, and no collective carries more than
+    the step's logits or its hidden states (in float32), whichever is
+    larger: no weight or pool crosses chips."""
     # the engine resolves "pallas" to interpret mode on this CPU host; a
     # step compiled for the chip needs the compiled kernel
     monkeypatch.setattr(BE, "_interpret_default", lambda: False)
-    arch, cut, slots, num_pages, max_len = CELLS[cell]
+    arch, cut, slots, num_pages, max_len, tp = CELLS[cell]
     cfg = C.get_config(arch, dtype=jnp.bfloat16, block=PAGE,
                        decode_backend="pallas", **cut)
     maxp = max_len // PAGE
+    params = jax.eval_shape(lambda k: M.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    pools = jax.eval_shape(lambda: M.init_paged_cache(
+        cfg, slots, num_pages, PAGE, max_len))
+    fn, donate = jitted_step_fns(cfg)[step]
+    if tp == 1:
+        rep = SingleDeviceSharding(v5e_2x2.devices[0])
+        param_sh, pool_sh = (jax.tree.map(lambda _: rep, t)
+                             for t in (params, pools))
+    else:
+        mesh = Mesh(np.array(v5e_2x2.devices[:tp]).reshape(1, tp),
+                    ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+        param_sh, pool_sh, rep = SH.serve_shardings(cfg, mesh, params, pools)
+        fn = AX.traced_under(mesh, fn)
 
-    def shaped(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
+    def shaped(tree, shardings):
+        return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=s), tree, shardings)
 
-    params = shaped(jax.eval_shape(lambda k: M.init_params(cfg, k),
-                                   jax.random.PRNGKey(0)))
-    pools = shaped(jax.eval_shape(lambda: M.init_paged_cache(
-        cfg, slots, num_pages, PAGE, max_len)))
     leaf = jax.tree.leaves(pools)[0]
     pool_bytes = sum(a.size * a.dtype.itemsize
-                     for a in jax.tree.leaves(pools))
+                     for a in jax.tree.leaves(pools)) // tp
 
     def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
 
-    args = {
-        "decode_step": (params, pools, i32(slots, 1), i32(slots),
-                        i32(slots, maxp),
+    args = (shaped(params, param_sh), shaped(pools, pool_sh)) + {
+        "decode_step": (i32(slots, 1), i32(slots), i32(slots, maxp),
                         jax.ShapeDtypeStruct((slots,), jnp.bool_,
-                                             sharding=one_chip)),
-        "prefill_chunk": (params, pools, i32(1, PAGE), i32(), i32(),
-                          i32(PAGE), i32(PAGE), i32(maxp), i32()),
+                                             sharding=rep)),
+        "prefill_chunk": (i32(1, PAGE), i32(), i32(), i32(PAGE), i32(PAGE),
+                          i32(maxp), i32()),
     }[step]
-    fn, donate = jitted_step_fns(cfg)[step]
-    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    out = jax.eval_shape(fn, *args)
+    logits = out[1] if step == "decode_step" else out[0]
+    tokens = slots if step == "decode_step" else PAGE
+    bound = max(logits.size * logits.dtype.itemsize, tokens * cfg.d_model * 4)
+    out_sh = jax.tree.map(lambda _: rep, out[:-1]) + (pool_sh,)
+    compiled = jax.jit(fn, in_shardings=jax.tree.map(
+        lambda a: a.sharding, args), out_shardings=out_sh,
+        donate_argnums=donate).lower(*args).compile()
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= pool_bytes
     assert ma.temp_size_in_bytes < pool_bytes // 20, ma.temp_size_in_bytes
-    sizes = {leaf.size, leaf.size // leaf.shape[0]}
-    moved = []
-    for line in compiled.as_text().splitlines():
+    sizes = {leaf.size // tp, leaf.size // tp // leaf.shape[0]}
+    moved, crossed = [], []
+    text = compiled.as_text()
+    for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* ([\w-]+)\(",
                      line)
         if m and m.group(2) in _POOL_OPS:
@@ -193,6 +235,10 @@ def test_engine_step_keeps_pool_in_place(one_chip, monkeypatch, cell, step):
                 n *= int(d)
             if n in sizes:
                 moved.append(line.strip()[:120])
+        m = _COLLECTIVE.match(line)
+        if m and max(_nbytes(*a) for a in _ARRAY.findall(m.group(1))) > bound:
+            crossed.append(line.strip()[:120])
     assert not moved, moved
-    kernels = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    assert not crossed, crossed
+    kernels = text.count('custom_call_target="tpu_custom_call"')
     assert kernels == (1 if step == "decode_step" else 0)
