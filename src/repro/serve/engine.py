@@ -188,6 +188,31 @@ def _paged_step(cfg: ModelConfig, params, caches, tokens, seq_pos, page_table,
     return greedy.astype(jnp.int32), logits, new_caches
 
 
+def sample_token(key, row, rid, k, temperature):
+    """Token ``k`` of request ``rid`` from its (V,) logits row: a draw of
+    ``softmax(row / T)`` as ``argmax(row / T + Gumbel)`` under the key
+    ``fold_in(fold_in(key, rid), k)``.  The key is per request and
+    position, so a draw does not depend on the slot or step that makes it."""
+    key = jax.random.fold_in(jax.random.fold_in(key, rid), k)
+    return jax.random.categorical(
+        key, row.astype(jnp.float32) / temperature
+    ).astype(jnp.int32)
+
+
+def sample_step(key, logits, rids, ks, temperature):
+    """:func:`sample_token` for each slot of the decode step's (B, 1, V)
+    logits: (B,) int32.  Idle slots draw too; their tokens are ignored."""
+    return jax.vmap(sample_token, (None, 0, 0, 0, None))(
+        key, logits[:, -1], rids, ks, temperature
+    )
+
+
+# the device sampler: its own programs beside the step programs, which they
+# follow on the same stream (no host sync in between); they touch no pool
+_sample_step_fn = jax.jit(sample_step)
+_sample_token_fn = jax.jit(sample_token)
+
+
 def _donate_caches() -> tuple:
     # donate the cache pytree (arg 1 of the partial-bound step fns): the
     # page pool is the dominant buffer and the engine always replaces its
@@ -501,11 +526,14 @@ class Engine:
             self._prefill = _prefill_fn(cfg)
             self._chunk_fn = _prefill_chunk_fn(cfg)
             self._decode = _decode_paged_fn(cfg)
-        # per-slot last sampled token, kept ON DEVICE: the greedy loop feeds
-        # decode outputs straight back in, syncing to host only at
-        # scheduling events (finish, preemption, EOS, temperature sampling)
+        # per-slot last sampled token, kept ON DEVICE: the decode loop feeds
+        # each step's tokens (greedy or sampled) straight back in, syncing to
+        # host only at scheduling events (first token, finish, preemption, EOS)
         self._last_tok = jnp.zeros((ec.max_seqs,), jnp.int32)
-        # deferred token log: (device (B,) greedy tokens, [(slot, req), ...])
+        # the sampler's base key and temperature, on the device once
+        self._key = jax.random.PRNGKey(ec.seed)
+        self._temperature = jnp.float32(ec.temperature)
+        # deferred token log: (device (B,) tokens, [(slot, req), ...])
         self._pending: List[tuple] = []
         self._rid_counter = 0
         self.step_count = 0
@@ -548,20 +576,21 @@ class Engine:
     # -- sampling -----------------------------------------------------------
 
     def _sample(self, row_logits: jnp.ndarray, req: Request) -> int:  # repro: hot-loop
-        """Sample one token from a (V,) logits row (fp32, greedy or temp)."""
-        lf = row_logits.astype(jnp.float32)
+        """Sample one token from a (V,) logits row (fp32, greedy or temp).
+
+        Only first tokens come here: the decode batch's tokens stay on the
+        device (:meth:`_decode_once`), and this sync runs at admissions."""
         if self.ec.temperature <= 0:
-            # callers that can defer use the on-device greedy feedback path,
-            # not _sample — this sync only runs at scheduling events
+            lf = row_logits.astype(jnp.float32)
             tok = jnp.argmax(lf)
         else:
-            # per-request, per-position key: independent of scheduling order
-            key = jax.random.fold_in(
-                jax.random.fold_in(jax.random.PRNGKey(self.ec.seed), req.rid),
-                len(req.out_tokens),
+            # the decode sampler's draw for one row: one program, one sync
+            tok = _sample_token_fn(
+                self._key, row_logits, np.uint32(req.rid),
+                np.uint32(req.n_generated), self._temperature,
             )
-            tok = jax.random.categorical(key, lf / self.ec.temperature)
-        # the host needs the value now: scheduling reads every sampled token
+            self.obs.sampled(1)
+        # the host needs the value now: scheduling reads every first token
         with self.obs.sync():
             return int(tok)  # repro: noqa RPR002 -- sanctioned sync
 
@@ -579,7 +608,7 @@ class Engine:
         if not self._pending:
             return
         with self.obs.span("serve.flush"):
-            # THE deferred-sync point: the only place the greedy decode loop
+            # THE deferred-sync point: the only place the decode loop
             # pays device->host, amortized over every step since the last flush
             with self.obs.sync(len(self._pending)):
                 rows = np.stack(  # repro: noqa RPR002 -- sanctioned deferred sync
@@ -751,21 +780,26 @@ class Engine:
             for slot, req in decoding:
                 seq_pos[slot] = req.next_pos
                 active[slot] = True
-            greedy, logits, self.kv.data = self._decode(
+            tokens, logits, self.kv.data = self._decode(
                 self.params, self.kv.data, self._last_tok[:, None],
                 jnp.asarray(seq_pos), self.kv.page_table(), jnp.asarray(active),
             )
         self.decode_steps += 1
         if self.ec.temperature > 0:
-            # host sampling needs the logits now — no deferral on this path
+            # draw the batch on the device, queued right behind the step:
+            # token k of each request, k counting tokens not yet on the host
             with self.obs.span("serve.sample"):
+                rids = np.zeros((self.ec.max_seqs,), np.uint32)  # idle -> 0
+                ks = np.zeros((self.ec.max_seqs,), np.uint32)
                 for slot, req in decoding:
-                    self._append_token(
-                        slot, req, self._sample(logits[slot, -1], req)
-                    )
-            return
-        self._last_tok = greedy  # feed back on-device; no host round-trip
-        self._pending.append((greedy, decoding))
+                    rids[slot] = req.rid
+                    ks[slot] = req.n_generated
+                tokens = _sample_step_fn(
+                    self._key, logits, rids, ks, self._temperature
+                )
+                self.obs.sampled(len(decoding))
+        self._last_tok = tokens  # feed back on-device; no host round-trip
+        self._pending.append((tokens, decoding))
         for slot, req in decoding:
             req.n_pending += 1
         if self.ec.eos_id is not None:

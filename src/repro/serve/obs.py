@@ -411,9 +411,11 @@ class Observability:
             ("pages_allocated_total", "cumulative pool page allocations"),
             ("generated_tokens_total", "tokens generated by finished requests"),
             ("host_syncs_total", "device arrays brought to the host (serve.sync)"),
+            ("sampled_rows_total", "rows of live slots drawn by the device sampler"),
         ):
             r.counter(name, help)
         self._host_syncs = r.counter("host_syncs_total")
+        self._sampled_rows = r.counter("sampled_rows_total")
         for name, help in (
             ("queue_depth", "requests waiting in the admission queue"),
             ("decode_batch_occupancy", "slots in the current decode batch"),
@@ -545,6 +547,10 @@ class Observability:
         r = self.registry
         r.gauge("decode_batch_occupancy").set(occupancy)
         r.histogram("decode_batch_occupancy").observe(occupancy)
+
+    def sampled(self, rows: int) -> None:  # repro: hot-loop
+        """``rows`` live slots drawn by the device sampler (temperature > 0)."""
+        self._sampled_rows.inc(rows)
 
     def step_begin(self) -> float:  # repro: hot-loop
         self._sync_s = 0.0

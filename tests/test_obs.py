@@ -443,19 +443,25 @@ def test_served_tokens_unchanged(served):
 
 
 def test_host_syncs_total_exact(served):
-    """One sync per first token, plus one per decode step when tokens stay
-    on the device (greedy: the step's tokens come over in one array) or one
-    per decoding slot per step when the host samples."""
+    """One sync per first token, plus one per decode step: greedy or
+    sampled, the step's tokens stay on the device and come over in one
+    array."""
+    _, eng, done = served
+    c = eng.metrics()["counters"]
+    assert c["preemptions_total"] == 0  # every admission samples one first token
+    assert c["host_syncs_total"] == c["decode_steps_total"] + len(done)
+
+
+def test_sampled_rows_total_counts_device_draws(served):
+    """The device sampler draws every decoding slot of every step and every
+    first token when the engine samples, and nothing under greedy."""
     mode, eng, done = served
     m = eng.metrics()
-    c = m["counters"]
-    assert c["preemptions_total"] == 0  # every admission samples one first token
-    first_tokens = len(done)
+    rows = m["counters"]["sampled_rows_total"]
     if MODES[mode].get("temperature", 0) > 0:
-        per_step = m["histograms"]["decode_batch_occupancy"]["sum"]
+        assert rows == m["histograms"]["decode_batch_occupancy"]["sum"] + len(done)
     else:
-        per_step = c["decode_steps_total"]
-    assert c["host_syncs_total"] == per_step + first_tokens
+        assert rows == 0
 
 
 def test_step_time_splits_into_host_and_sync_wait(served):

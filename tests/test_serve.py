@@ -28,6 +28,7 @@ from repro.serve import (
     bucket_tokens,
     make_requests,
 )
+from repro.serve.engine import sample_step
 
 
 # --------------------------------------------------------------------------
@@ -289,7 +290,11 @@ def test_eos_early_stop_matches_baseline_prefix():
     assert hit_early >= 1  # the chosen eos truncated at least one request
 
 
-def test_temperature_sampling_schedule_independent():
+# eos 70 is request 0's third draw under seed 11: with it set, a request
+# stops early and the step's tokens come over each step; without it they
+# stay deferred, so a draw's position counts tokens not yet on the host
+@pytest.mark.parametrize("eos_id", [None, 70], ids=["deferred", "eos"])
+def test_temperature_sampling_schedule_independent(eos_id):
     """Per-request fold_in(seed, rid, position) keys: sampled outputs must
     not depend on slot count / scheduling interleave."""
     cfg = _paged_cfg(block=8)
@@ -298,10 +303,10 @@ def test_temperature_sampling_schedule_independent():
     prompts = [rng.integers(0, cfg.vocab_size, size=(9,)).astype(np.int32)
                for _ in range(3)]
 
-    def sample_with(max_seqs):
+    def sample_with(max_seqs, eos=eos_id):
         eng = Engine(cfg, params, EngineConfig(
             max_seqs=max_seqs, max_len=24, page_size=8,
-            temperature=0.8, seed=11,
+            temperature=0.8, seed=11, eos_id=eos,
         ))
         for i, p in enumerate(prompts):
             eng.submit(p, 6, rid=i)
@@ -312,6 +317,32 @@ def test_temperature_sampling_schedule_independent():
     assert serial == batched
     # and distinct requests don't share a key stream
     assert len({tuple(v) for v in serial.values()}) > 1
+    if eos_id is not None:
+        assert serial[0][-1] == eos_id and len(serial[0]) < 6
+    # an eos no token equals brings every step's tokens to the host at once:
+    # deferred or not, each request draws the same stream
+    stepwise = sample_with(3, eos=-1)
+    assert all(stepwise[r][:len(v)] == v for r, v in serial.items())
+
+
+def test_batch_sampler_draws_each_rows_token():
+    """The device sampler's batch is, bit for bit, each row's own
+    ``categorical(fold_in(fold_in(PRNGKey(seed), rid), k), row / T)``:
+    idle rows with dummy keys, distinct positions, pad columns masked as
+    the model masks them."""
+    seed, T, V, Vp = 11, 0.7, 500, 512
+    rows = jax.random.normal(jax.random.PRNGKey(3), (5, Vp)) * 3
+    rows = rows.at[:, V:].set(jnp.finfo(jnp.float32).min)
+    rids = np.array([7, 0, 7, 123456, 0], np.uint32)  # rows 1, 4 idle
+    ks = np.array([0, 0, 5, 2, 0], np.uint32)
+    got = jax.jit(sample_step)(jax.random.PRNGKey(seed), rows[:, None],
+                               rids, ks, jnp.float32(T))
+    assert got.dtype == jnp.int32 and got.shape == (5,)
+    for i in range(5):
+        key = jax.random.fold_in(
+            jax.random.fold_in(jax.random.PRNGKey(seed), int(rids[i])),
+            int(ks[i]))
+        assert int(got[i]) == int(jax.random.categorical(key, rows[i] / T)) < V
 
 
 def test_continuous_batching_step_efficiency():
