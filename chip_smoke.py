@@ -362,8 +362,8 @@ def main() -> None:
         import jax.numpy as jnp
 
         import repro.configs as C
-        from repro.core.backend import resolve_backend
         from repro.launch.compile_cache import use_compile_cache
+        from repro.models import attention as attn
         from repro.models import model as M
     except ImportError as e:
         fail(f"cannot import the program ({e}); run from the repo root")
@@ -372,8 +372,8 @@ def main() -> None:
     jax.monitoring.register_event_duration_secs_listener(_on_event)
     check(jax.default_backend() == "tpu",
           f"JAX runs on {jax.default_backend()!r}, not a TPU")
-    check(resolve_backend("pallas").interpret is False,
-          "the Pallas kernels would run in interpret mode")
+    check(not attn.kernels_interpreted(),
+          "the paged kernels would run in interpret mode")
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices())}

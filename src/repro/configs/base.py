@@ -82,11 +82,12 @@ class ModelConfig:
     gemm_backend: str = "xla"
     block: int = 128  # accelerator block (BWMA quantum) when using kernels
 
-    # serving-engine paged-decode execution path (resolve_backend name):
-    # "reference" reads pages through the jnp gather->attend oracle;
-    # "pallas" streams pages through the fused paged-attention / paged-COW
-    # kernels (interpret mode on the CPU platform only).  Part of the
-    # frozen config on purpose: every jitted step cache is keyed by it.
+    # serving-engine paged-decode execution path, chosen in
+    # repro.models.attention (DECODE_BACKENDS): "reference" reads pages
+    # through the jnp gather->attend oracle; "pallas" streams pages through
+    # the fused paged-attention / paged-COW kernels (interpret mode on the
+    # CPU platform only).  Part of the frozen config on purpose: every
+    # jitted step cache is keyed by it.
     decode_backend: str = "reference"
 
     @property

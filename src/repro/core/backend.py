@@ -20,15 +20,6 @@ the paper's "Activation" category — no data movement depends on arrangement,
 so there is nothing for a kernel backend to change (the FFN fusion handles
 the one case where fusing them into a GEMM epilogue matters).
 
-The protocol also carries the serving engine's paged-decode operators
-(``paged_attention_decode``, ``mla_paged_attention_decode``,
-``paged_copy_page``): the engine's KV pages are sized to ``cfg.block``, so
-they are already kernel tiles — the reference backend reads them through
-the jnp gather->attend oracle, the pallas backend streams them page-by-page
-through the fused kernels in :mod:`repro.kernels.paged_attention`.  The
-engine selects per :attr:`ModelConfig.decode_backend` via
-:func:`resolve_backend`.
-
 Select a backend by name or instance::
 
     from repro.core import backend as B
@@ -43,8 +34,6 @@ from typing import Callable, Dict, Optional, Protocol, Union, runtime_checkable
 
 import jax
 
-from jax.sharding import PartitionSpec as _P
-
 from repro.core import blockwise as bw
 from repro.core.blockwise import Blocked
 from repro.kernels.bwma_attention import bwma_attention
@@ -53,12 +42,6 @@ from repro.kernels.bwma_gemm import bwma_gemm
 from repro.kernels.bwma_layernorm import bwma_layernorm
 from repro.kernels.bwma_softmax import bwma_softmax
 from repro.kernels.bwma_transpose import bwma_transpose
-from repro.kernels.paged_attention import (
-    KV_HEAD_AXIS,
-    mla_paged_attention_decode,
-    paged_attention_decode,
-    paged_copy,
-)
 
 
 @runtime_checkable
@@ -84,24 +67,6 @@ class Backend(Protocol):
     def attention(self, q: Blocked, k: Blocked, v: Blocked, *, scale) -> Blocked: ...
 
     def transpose(self, a: Blocked) -> Blocked: ...
-
-    # -- serving-engine paged-decode operators (raw arrays, not Blocked:
-    # -- the engine's pages already ARE kernel tiles — page size is
-    # -- cfg.block — so there is no separate blocked arrangement step).
-    # -- Pools are layer-stacked (L, num_pages, *block); the decode reads
-    # -- take the layer index, the COW copy moves a page in every layer,
-    # -- and ``head_axis`` names the stacked pools' kv-head axis (None:
-    # -- the pools have none) --
-
-    def paged_attention_decode(self, q, k_pages, v_pages, page_table,
-                               seq_pos, layer): ...
-
-    def mla_paged_attention_decode(self, q_lat, q_rope, ckv_pages,
-                                   krope_pages, page_table, seq_pos, layer,
-                                   *, scale): ...
-
-    def paged_copy_page(self, pools: Dict, src, dst,
-                        head_axis: Optional[int] = None) -> Dict: ...
 
     # -- layout-neutral element-wise ops (shared implementations) --
 
@@ -153,37 +118,6 @@ class ReferenceBackend(_ElementwiseMixin):
     def transpose(self, a: Blocked) -> Blocked:
         return bw.bw_transpose(a)
 
-    # -- paged-decode operators: the jnp gather->attend oracle paths.
-    # Lazy imports (models sits above core in the layering; the reference
-    # math lives next to the cache layouts it reads, mirroring how
-    # models.common.dense lazily resolves this module in the other
-    # direction).
-
-    def paged_attention_decode(self, q, k_pages, v_pages, page_table,
-                               seq_pos, layer):
-        from repro.models import attention as attn
-
-        return attn.paged_gather_attend(
-            q, k_pages, v_pages, page_table, seq_pos, layer
-        )
-
-    def mla_paged_attention_decode(self, q_lat, q_rope, ckv_pages,
-                                   krope_pages, page_table, seq_pos, layer,
-                                   *, scale):
-        from repro.models import attention as attn
-
-        return attn.mla_paged_gather_attend(
-            q_lat, q_rope, ckv_pages, krope_pages, page_table, seq_pos,
-            layer, scale=scale,
-        )
-
-    def paged_copy_page(self, pools: Dict, src, dst,
-                        head_axis: Optional[int] = None) -> Dict:
-        # jnp ops: GSPMD partitions them whatever the pools' sharding
-        from repro.models import attention as attn
-
-        return attn.paged_copy_page(pools, src, dst)
-
 
 def _interpret_default() -> bool:
     """Interpret Pallas kernels on the CPU platform only."""
@@ -216,18 +150,6 @@ class PallasBackend(_ElementwiseMixin):
             static_argnames=("scale",),
         )
         self._transpose = jax.jit(functools.partial(bwma_transpose, interpret=ip))
-        # the paged-decode kernels are deliberately NOT jitted here: they
-        # trace inline inside the engine's already-jitted decode / COW
-        # steps (a nested pjit would hazard the donation aliasing the
-        # engine's in-place pool update depends on); standalone callers
-        # (benchmarks, tests) jit them as needed
-        self._paged_attention_decode = functools.partial(
-            paged_attention_decode, interpret=ip
-        )
-        self._mla_paged_attention_decode = functools.partial(
-            mla_paged_attention_decode, interpret=ip
-        )
-        self._paged_copy = functools.partial(paged_copy, interpret=ip)
 
     @property
     def interpret(self) -> bool:
@@ -252,87 +174,6 @@ class PallasBackend(_ElementwiseMixin):
 
     def transpose(self, a: Blocked) -> Blocked:
         return self._transpose(a)
-
-    # -- tensor-parallel dispatch: a pallas_call cannot be auto-partitioned
-    # -- by GSPMD, so under an active TP shard policy (the engine's
-    # -- mesh-traced steps install one) the paged kernels run PER SHARD via
-    # -- shard_map with the head axis pre-partitioned.  Attention is
-    # -- head-independent, so the per-shard online softmax is bit-identical
-    # -- to the unsharded kernel on each head — the streamed pages never
-    # -- cross devices and no collective is inserted here (only the
-    # -- post-attention row-parallel projection all-reduces, outside).
-
-    @staticmethod
-    def _tp_policy(*head_counts):
-        """The active TP policy when the head axes can shard, else None."""
-        from repro.distributed import axes as AX
-
-        pol = AX.current()
-        if (pol is not None and pol.mesh is not None and pol.tp_size > 1
-                and all(h % pol.tp_size == 0 for h in head_counts)):
-            return pol
-        return None
-
-    @staticmethod
-    def _axis_spec(ndim: int, axis: int, name: str):
-        return _P(*(name if a == axis else None for a in range(ndim)))
-
-    def paged_attention_decode(self, q, k_pages, v_pages, page_table,
-                               seq_pos, layer):
-        pol = self._tp_policy(q.shape[2], k_pages.shape[KV_HEAD_AXIS])
-        if pol is not None:
-            tp = pol.tp_axis
-            head = _P(None, None, tp, None)
-            pool = self._axis_spec(k_pages.ndim, KV_HEAD_AXIS, tp)
-            return jax.shard_map(
-                self._paged_attention_decode, mesh=pol.mesh,
-                in_specs=(head, pool, pool, _P(), _P(), _P()),
-                out_specs=head, check_vma=False,
-            )(q, k_pages, v_pages, page_table, seq_pos, layer)
-        return self._paged_attention_decode(
-            q, k_pages, v_pages, page_table, seq_pos, layer
-        )
-
-    def mla_paged_attention_decode(self, q_lat, q_rope, ckv_pages,
-                                   krope_pages, page_table, seq_pos, layer,
-                                   *, scale):
-        pol = self._tp_policy(q_lat.shape[2])
-        if pol is not None:
-            # latent pages carry no head axis: they stay replicated and each
-            # device attends its own query heads against the full pools
-            head = _P(None, None, pol.tp_axis, None)
-            return jax.shard_map(
-                functools.partial(
-                    self._mla_paged_attention_decode, scale=scale
-                ),
-                mesh=pol.mesh,
-                in_specs=(head, head, _P(), _P(), _P(), _P(), _P()),
-                out_specs=head, check_vma=False,
-            )(q_lat, q_rope, ckv_pages, krope_pages, page_table, seq_pos,
-              layer)
-        return self._mla_paged_attention_decode(
-            q_lat, q_rope, ckv_pages, krope_pages, page_table, seq_pos,
-            layer, scale=scale,
-        )
-
-    def paged_copy_page(self, pools: Dict, src, dst,
-                        head_axis: Optional[int] = None) -> Dict:
-        out = {}
-        for name, pool in pools.items():
-            # pools with a kv-head axis (dense/GQA K/V) COW-copy per head
-            # shard; headless pools (MLA latent) copy replicated
-            pol = (self._tp_policy(pool.shape[head_axis])
-                   if head_axis is not None else None)
-            if pol is not None:
-                spec = self._axis_spec(pool.ndim, head_axis, pol.tp_axis)
-                out[name] = jax.shard_map(
-                    self._paged_copy, mesh=pol.mesh,
-                    in_specs=(spec, _P(), _P()),
-                    out_specs=spec, check_vma=False,
-                )(pool, src, dst)
-            else:
-                out[name] = self._paged_copy(pool, src, dst)
-        return out
 
 
 BACKENDS: Dict[str, Callable[..., Backend]] = {

@@ -107,7 +107,6 @@ def run_workload(cfg, params, args, mesh=None):
             chunked_prefill=not args.no_chunked_prefill,
             prefill_chunk=args.prefill_chunk,
             prefill_tokens_per_step=args.prefill_tokens_per_step,
-            prefill_chunks_per_step=args.prefill_chunks_per_step,
             prefix_sharing=not args.no_prefix_sharing,
             backend=args.backend,
             debug_audit=args.debug_audit,
@@ -198,12 +197,7 @@ def main():
     ap.add_argument("--prefill-tokens-per-step", type=int, default=0,
                     help="prompt tokens admitted per engine step before the "
                          "decode batch steps (page-granular; the "
-                         "latency/throughput knob).  0 derives from the "
-                         "deprecated --prefill-chunks-per-step alias")
-    ap.add_argument("--prefill-chunks-per-step", type=int, default=None,
-                    help="DEPRECATED alias: admission budget as a chunk "
-                         "count (use --prefill-tokens-per-step; setting "
-                         "this emits a one-shot DeprecationWarning)")
+                         "latency/throughput knob).  0 derives 4 chunks")
     ap.add_argument("--no-chunked-prefill", action="store_true",
                     help="one-shot prefill per admission (the pre-chunking "
                          "behavior; still installed via donating jit)")
@@ -244,12 +238,6 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     use_compile_cache()
-
-    if args.prefill_chunks_per_step is not None:
-        # one-shot per process; the Engine would also warn, but the flag
-        # deserves the notice even on paths that never build an Engine
-        from repro.serve.engine import warn_prefill_chunks_deprecated
-        warn_prefill_chunks_deprecated()
 
     cfg = C.get_config(args.arch, smoke=args.smoke,
                        dtype=jnp.float32 if args.smoke else jnp.bfloat16)

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.backend import resolve_backend
 from repro.kernels.paged_attention import KV_HEAD_AXIS, kv_page_order
 from repro.models import attention as attn
 from repro.models import ssm as ssmm
@@ -228,9 +227,7 @@ class PagedAttnAdapter(CacheAdapter):
         return attn.paged_cache_init(cfg, geom.num_pages, geom.page_size)
 
     def copy_page(self, cfg, seg_cache, src, dst):
-        return resolve_backend(cfg.decode_backend).paged_copy_page(
-            seg_cache, src, dst, head_axis=KV_HEAD_AXIS
-        )
+        return attn.cow_copy_page(cfg, seg_cache, src, dst)
 
     def pool_pspecs(self, cfg, *, tp_axis="model", tp_size=1):
         # stacked pools are (L, num_pages, *block): shard the kv-head axis
@@ -351,9 +348,7 @@ class LatentMLAAdapter(CacheAdapter):
         return {"ckv_pages": P(), "krope_pages": P()}
 
     def copy_page(self, cfg, seg_cache, src, dst):
-        return resolve_backend(cfg.decode_backend).paged_copy_page(
-            seg_cache, src, dst
-        )
+        return attn.cow_copy_page(cfg, seg_cache, src, dst)
 
     def install(self, cfg, dst, src, slot, phys_tok, off_tok):
         return _install_paged(dst, src, phys_tok, off_tok,

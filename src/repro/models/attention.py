@@ -12,15 +12,23 @@ point of MLA — and uses the absorbed-matmul formulation at decode time.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.backend import resolve_backend
+from repro.distributed import axes as AX
 from repro.distributed.axes import constrain
-from repro.kernels.paged_attention import kv_page_order
+from repro.kernels.paged_attention import (
+    KV_HEAD_AXIS,
+    kv_page_order,
+    mla_paged_attention_decode,
+    paged_attention_decode,
+    paged_copy,
+)
 from repro.models.common import (
     apply_mrope,
     apply_rope,
@@ -251,7 +259,8 @@ def _kv_tokens(pages, d_head: int):
 
 
 def paged_gather_attend(q, k_pages, v_pages, page_table, seq_pos, layer):
-    """The jnp gather->attend oracle read (the ``"reference"`` backend op).
+    """The jnp gather->attend oracle read (the ``"reference"`` path of
+    :func:`paged_attend`).
 
     Gathers each slot's logical pages of layer ``layer`` back into a dense
     (B, max_pages*page, Hkv, dh) HBM buffer and runs the same masked
@@ -289,10 +298,10 @@ def gqa_paged_decode(
     Write: the new K/V lands in page ``page_table[b, pos // page]`` at offset
     ``pos % page`` of the stacked pool, which the layer scan carries whole:
     no slice of the pool is cut out or written back.  Read: through
-    ``cfg.decode_backend`` — the reference
-    backend gathers each slot's logical pages back into order and runs the
+    :func:`paged_attend` — the reference
+    path gathers each slot's logical pages back into order and runs the
     linear cache's masked attention (:func:`paged_gather_attend`); the
-    pallas backend streams the page-table row through the fused kernel
+    pallas path streams the page-table row through the fused kernel
     without materializing the gathered history.
 
     ``active`` marks slots whose write should land: inactive slots (idle,
@@ -316,9 +325,7 @@ def gqa_paged_decode(
                                 off[:, None], k, page_axis)
     v_pages = write_page_tokens(cache["v_pages"], layer, phys[:, None],
                                 off[:, None], v, page_axis)
-    be = resolve_backend(cfg.decode_backend)
-    out = be.paged_attention_decode(q, k_pages, v_pages, page_table, seq_pos,
-                                    layer)
+    out = paged_attend(cfg, q, k_pages, v_pages, page_table, seq_pos, layer)
     out = out.reshape(B, 1, cfg.n_heads * cfg.d_head)
     return dense(cfg, out, p["wo"]), {"k_pages": k_pages, "v_pages": v_pages}
 
@@ -336,16 +343,133 @@ def paged_copy_page(cache: Dict, src, dst) -> Dict:
     and MLA latent pages alike (the page-id axis is axis 1 after the layer
     stack).
 
-    This dense dynamic-slice copy is the ``"reference"`` backend's op; the
-    adapters dispatch through ``cfg.decode_backend``, and the pallas
-    backend replaces it with the scalar-prefetched single-page copy kernel
-    (:func:`repro.kernels.paged_attention.paged_copy`) — bit-exact either
-    way.
+    This dense dynamic-slice copy is the ``"reference"`` path of
+    :func:`cow_copy_page`, whose ``"pallas"`` path is the scalar-prefetched
+    single-page copy kernel (:func:`repro.kernels.paged_attention.paged_copy`)
+    — bit-exact either way.
     """
     out = {}
     for name, pool in cache.items():
         row = jax.lax.dynamic_slice_in_dim(pool, src, 1, axis=1)
         out[name] = jax.lax.dynamic_update_slice_in_dim(pool, row, dst, axis=1)
+    return out
+
+
+# --------------------------------------------------------------------------
+# Paged-decode execution path: the jnp oracles or the Pallas kernels
+# --------------------------------------------------------------------------
+# ``cfg.decode_backend`` picks, per op, the jnp oracle (``"reference"``) or
+# the fused kernel of :mod:`repro.kernels.paged_attention` (``"pallas"``).
+# The kernels are not jitted here: they trace inline in the engine's
+# donating step jits (a nested jit would hazard the donation aliasing the
+# in-place pool update depends on).  A pallas_call cannot be partitioned by
+# GSPMD, so under an active tensor-parallel policy (the engine's
+# mesh-traced steps install one) a kernel runs per shard through
+# ``shard_map``, the head axis split over the model axis.  Attention is
+# head-independent, so each shard's online softmax is the unsharded
+# kernel's on its own heads: pages never cross devices, and no collective
+# is inserted here (only the row-parallel ``wo`` all-reduces, outside).
+
+#: the values of ``ModelConfig.decode_backend``
+DECODE_BACKENDS = ("reference", "pallas")
+
+
+def uses_paged_kernels(backend: str) -> bool:
+    """Whether the decode path ``backend`` names runs the Pallas kernels
+    (``"pallas"``) rather than the jnp oracles (``"reference"``)."""
+    if backend not in DECODE_BACKENDS:
+        raise ValueError(f"unknown backend {backend!r}; available: "
+                         f"{sorted(DECODE_BACKENDS)}")
+    return backend == "pallas"
+
+
+def kernels_interpreted() -> bool:
+    """The paged kernels run in the Pallas interpreter on the CPU platform
+    alone: any other platform compiles them, or fails."""
+    return jax.default_backend() == "cpu"
+
+
+def _tp_policy(*head_counts):
+    """The active TP policy when every head count divides over its model
+    axis, else None."""
+    pol = AX.current()
+    if (pol is not None and pol.mesh is not None and pol.tp_size > 1
+            and all(h % pol.tp_size == 0 for h in head_counts)):
+        return pol
+    return None
+
+
+def _on_axis(ndim: int, axis: int, name: str) -> P:
+    return P(*(name if a == axis else None for a in range(ndim)))
+
+
+def paged_attend(cfg: ModelConfig, q, k_pages, v_pages, page_table, seq_pos,
+                 layer):
+    """Dense/GQA one-token attention over layer ``layer`` of the stacked K/V
+    pools: :func:`paged_gather_attend`, or the fused kernel per KV-head
+    shard."""
+    if not uses_paged_kernels(cfg.decode_backend):
+        return paged_gather_attend(q, k_pages, v_pages, page_table, seq_pos,
+                                   layer)
+    kernel = functools.partial(paged_attention_decode,
+                               interpret=kernels_interpreted())
+    pol = _tp_policy(q.shape[2], k_pages.shape[KV_HEAD_AXIS])
+    if pol is not None:
+        head = P(None, None, pol.tp_axis, None)
+        pool = _on_axis(k_pages.ndim, KV_HEAD_AXIS, pol.tp_axis)
+        kernel = jax.shard_map(
+            kernel, mesh=pol.mesh,
+            in_specs=(head, pool, pool, P(), P(), P()),
+            out_specs=head, check_vma=False,
+        )
+    return kernel(q, k_pages, v_pages, page_table, seq_pos, layer)
+
+
+def mla_paged_attend(cfg: ModelConfig, q_lat, q_rope, ckv_pages, krope_pages,
+                     page_table, seq_pos, layer, *, scale):
+    """MLA absorbed attention over layer ``layer`` of the stacked latent
+    pools: :func:`mla_paged_gather_attend`, or the fused kernel per
+    query-head shard."""
+    if not uses_paged_kernels(cfg.decode_backend):
+        return mla_paged_gather_attend(q_lat, q_rope, ckv_pages, krope_pages,
+                                       page_table, seq_pos, layer, scale=scale)
+    kernel = functools.partial(mla_paged_attention_decode, scale=scale,
+                               interpret=kernels_interpreted())
+    pol = _tp_policy(q_lat.shape[2])
+    if pol is not None:
+        # latent pages carry no head axis: they stay replicated and each
+        # device attends its own query heads against the full pools
+        head = P(None, None, pol.tp_axis, None)
+        kernel = jax.shard_map(
+            kernel, mesh=pol.mesh,
+            in_specs=(head, head, P(), P(), P(), P(), P()),
+            out_specs=head, check_vma=False,
+        )
+    return kernel(q_lat, q_rope, ckv_pages, krope_pages, page_table, seq_pos,
+                  layer)
+
+
+def cow_copy_page(cfg: ModelConfig, pools: Dict, src, dst) -> Dict:
+    """Copy page ``src`` -> ``dst`` in every stacked pool of ``pools``:
+    :func:`paged_copy_page` (jnp ops, which GSPMD partitions), or the copy
+    kernel — per KV-head shard for a K/V pool, replicated for MLA's latent
+    pools, which carry no head axis."""
+    if not uses_paged_kernels(cfg.decode_backend):
+        return paged_copy_page(pools, src, dst)
+    copy = functools.partial(paged_copy, interpret=kernels_interpreted())
+    out = {}
+    for name, pool in pools.items():
+        # a K/V pool is (L, num_pages, *3-d block), a latent one
+        # (L, num_pages, page, r)
+        pol = _tp_policy(pool.shape[KV_HEAD_AXIS]) if pool.ndim == 5 else None
+        if pol is not None:
+            spec = _on_axis(pool.ndim, KV_HEAD_AXIS, pol.tp_axis)
+            out[name] = jax.shard_map(
+                copy, mesh=pol.mesh, in_specs=(spec, P(), P()),
+                out_specs=spec, check_vma=False,
+            )(pool, src, dst)
+        else:
+            out[name] = copy(pool, src, dst)
     return out
 
 
@@ -563,7 +687,7 @@ def mla_latent_attend(q_lat, q_rope, ckv_c, kr_c, valid, *, scale):
     ``q_lat``: (B, S, H, r) — q_nope already absorbed through ``W_kv_b``;
     ``valid``: (B, K) key mask.  Returns the latent-space output ``o_lat``
     (B, S, H, r) — the caller applies the value expansion.  This is the
-    ``"reference"`` backend's MLA decode read (over gathered latents); the
+    ``"reference"`` MLA decode read (over gathered latents); the
     pallas kernel reproduces exactly this math page-by-page.
     """
     s = jnp.einsum("bshr,bkr->bhsk", q_lat, ckv_c,
@@ -711,11 +835,11 @@ def mla_paged_decode(
 
     Write: the new token's (c_kv, k_rope) lands in its slot's page of the
     stacked pool the layer scan carries.  Read:
-    through ``cfg.decode_backend``, always in the absorbed formulation —
+    through :func:`mla_paged_attend`, always in the absorbed formulation —
     q_nope is folded into the latent space through ``W_kv_b`` so attention
     runs over rank-r latents, never materializing per-head K/V.  The
-    reference backend gathers the latent pages into logical order
-    (:func:`mla_paged_gather_attend`); the pallas backend streams them
+    reference path gathers the latent pages into logical order
+    (:func:`mla_paged_gather_attend`); the pallas path streams them
     page-by-page through the fused kernel.  Either way entries sit at
     their absolute positions, so masking by ``k_pos <= seq_pos``
     reproduces the linear cache's valid set exactly (stale pages /
@@ -741,11 +865,8 @@ def mla_paged_decode(
 
     scale = (dn + cfg.qk_rope_dim) ** -0.5
     q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, wkv_b[..., :dn])
-    be = resolve_backend(cfg.decode_backend)
-    o_lat = be.mla_paged_attention_decode(
-        q_lat, q_rope, ckv_pages, krope_pages, page_table, seq_pos, layer,
-        scale=scale,
-    )
+    o_lat = mla_paged_attend(cfg, q_lat, q_rope, ckv_pages, krope_pages,
+                             page_table, seq_pos, layer, scale=scale)
     out = jnp.einsum("bshr,rhd->bshd", o_lat, wkv_b[..., dn:])  # value expand
     out = out.reshape(B, 1, H * dv)
     return out @ p["wo"], {"ckv_pages": ckv_pages, "krope_pages": krope_pages}

@@ -44,7 +44,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import warnings
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -52,10 +51,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core.backend import resolve_backend
 from repro.distributed import axes as AX
 from repro.distributed import sharding as SH
 from repro.models import adapters as A
+from repro.models import attention as attn
 from repro.models import model as M
 from repro.models.model import frontend_extras  # re-exported for callers
 from repro.serve.kvcache import PagedCacheConfig, PagedKVCache
@@ -372,10 +371,8 @@ class EngineConfig:
     values bound the latency a long prompt can inject between two decode
     steps of the running batch.  The budget is spent page-granularly (the
     chunk is the execution quantum, and chunks are page-sized), so the
-    effective budget rounds up to whole chunks.  ``0`` derives it from the
-    DEPRECATED chunk-count alias ``prefill_chunks_per_step`` (budget =
-    chunks x chunk size); setting the alias explicitly emits a one-shot
-    ``DeprecationWarning`` (leave it None for the default of 4 chunks).
+    effective budget rounds up to whole chunks.  ``0`` derives it as 4
+    chunks.
 
     ``chunked_prefill=False`` falls back to one-shot prefill per admission
     (still installed through the jitted donating updater).
@@ -388,7 +385,7 @@ class EngineConfig:
     path, and MoE stacks alias pages but recompute every token.
 
     ``backend`` selects the paged-decode execution path
-    (:func:`repro.core.backend.resolve_backend` name): ``"reference"``
+    (:data:`repro.models.attention.DECODE_BACKENDS`): ``"reference"``
     keeps the jnp gather->attend decode and dense COW copy; ``"pallas"``
     streams pages through the fused paged-attention / paged-copy kernels
     (interpret mode on the CPU platform only).  Folded into
@@ -401,8 +398,7 @@ class EngineConfig:
     num_pages: int = 0
     chunked_prefill: bool = True
     prefill_chunk: int = 0
-    prefill_tokens_per_step: int = 0  # 0: derive from the deprecated alias
-    prefill_chunks_per_step: Optional[int] = None  # DEPRECATED alias
+    prefill_tokens_per_step: int = 0  # 0: 4 chunks
     prefix_sharing: bool = True
     backend: str = "reference"  # paged-decode path: reference | pallas
     temperature: float = 0.0  # 0 = greedy
@@ -418,26 +414,7 @@ class EngineConfig:
     obs: bool = False
 
 
-_DEFAULT_CHUNKS_PER_STEP = 4  # the alias's historical default
-
-_chunks_alias_warned = False
-
-
-def warn_prefill_chunks_deprecated() -> None:
-    """One-shot DeprecationWarning for the ``prefill_chunks_per_step``
-    chunk-count alias (per process; the launch driver and EngineConfig
-    consumers both funnel through here)."""
-    global _chunks_alias_warned
-    if _chunks_alias_warned:
-        return
-    _chunks_alias_warned = True
-    warnings.warn(
-        "prefill_chunks_per_step is deprecated: the admission budget is "
-        "token-level now — set prefill_tokens_per_step (the chunk-count "
-        "alias still maps to chunks x chunk size, but will be removed)",
-        DeprecationWarning,
-        stacklevel=3,
-    )
+_DEFAULT_CHUNKS_PER_STEP = 4  # chunks a step when no token budget is set
 
 
 class Engine:
@@ -447,8 +424,8 @@ class Engine:
         # fold the backend selector into the frozen config: every memoized
         # step jit (_decode_paged_fn, _cow_fn, ...) keys on the ModelConfig,
         # so reference and pallas engines coexist without cache collisions.
-        # Resolve eagerly so an unknown name fails here, not mid-trace.
-        resolve_backend(ec.backend)
+        # Check eagerly so an unknown name fails here, not mid-trace.
+        attn.uses_paged_kernels(ec.backend)
         if ec.backend != cfg.decode_backend:
             cfg = dataclasses.replace(cfg, decode_backend=ec.backend)
         self.cfg, self.params, self.ec, self.mesh = cfg, params, ec, mesh
@@ -477,18 +454,10 @@ class Engine:
         self.chunk_size = self._resolve_chunk(ec.prefill_chunk)
         if ec.prefill_tokens_per_step < 0:
             raise ValueError("prefill_tokens_per_step must be >= 0")
-        chunks_alias = ec.prefill_chunks_per_step
-        if chunks_alias is None:
-            chunks_alias = _DEFAULT_CHUNKS_PER_STEP
-        else:
-            warn_prefill_chunks_deprecated()
-        if ec.prefill_tokens_per_step == 0 and chunks_alias < 1:
-            # the deprecated alias is only validated when it is actually used
-            raise ValueError("prefill_chunks_per_step must be >= 1")
-        # token-level admission budget; the deprecated chunk-count knob
-        # aliases to (chunks x chunk size) when no token budget is given
+        # token-level admission budget
         self.tokens_per_step = (
-            ec.prefill_tokens_per_step or chunks_alias * self.chunk_size
+            ec.prefill_tokens_per_step
+            or _DEFAULT_CHUNKS_PER_STEP * self.chunk_size
         )
         # adapters installing request-level context once at admission
         # (enc-dec encoder K/V) — resolved from the registry, not by family

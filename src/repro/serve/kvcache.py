@@ -117,8 +117,8 @@ def cow_step(cfg: ModelConfig):
 
     Copies ``src`` -> ``dst`` in every *shareable paged* adapter's pools —
     non-shareable pools (rings, SSM rows, cross rows) pass through
-    untouched.  Each adapter's ``copy_page`` dispatches on
-    ``cfg.decode_backend``: the reference path is a dense dynamic-slice
+    untouched.  Each adapter's ``copy_page`` goes through
+    :func:`repro.models.attention.cow_copy_page`: the reference path is a dense dynamic-slice
     copy, the pallas path a scalar-prefetched single-page copy kernel;
     both are bit-exact and keep the donated pool aliased in place.
     """

@@ -5,6 +5,9 @@ The acceptance bar for the kernel-backed path: ``encoder_bwma`` with
 baseline and the reference blockwise backend to <= 1e-4 max abs error on
 BERT-base-shaped inputs, including ragged (non-block-multiple) shapes.
 """
+import ast
+from pathlib import Path
+
 import jax
 import numpy as np
 import pytest
@@ -13,11 +16,15 @@ from repro.core import blockwise as bw
 from repro.core import encoder as enc
 from repro.core.backend import (
     BACKENDS,
+    Backend,
     PallasBackend,
     ReferenceBackend,
     resolve_backend,
 )
 from repro.core.layout import BlockLayout
+from repro.models import attention as attn
+
+SRC = Path(__file__).resolve().parents[1] / "src"
 
 
 def _cfg(**kw):
@@ -64,6 +71,39 @@ def test_interpret_only_on_cpu(monkeypatch, platform, interpret):
     monkeypatch.setattr(jax, "default_backend", lambda: platform)
     assert PallasBackend().interpret is interpret
     assert PallasBackend(interpret=True).interpret
+    assert attn.kernels_interpreted() is interpret  # the paged kernels'
+
+
+def _imported_modules(path: Path):
+    """Every module a source file imports, function-level imports
+    included, relative ones resolved against its package."""
+    package = ".".join(path.relative_to(SRC).parent.parts)
+    for node in ast.walk(ast.parse(path.read_text(), str(path))):
+        if isinstance(node, ast.Import):
+            yield from (alias.name for alias in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            base = package.rsplit(".", node.level - 1)[0] if node.level else ""
+            mod = ".".join(filter(None, (base, node.module)))
+            yield mod
+            yield from (f"{mod}.{alias.name}" for alias in node.names)
+
+
+def test_core_and_kernels_import_nothing_above_them():
+    """The paper's blocked encoder (``repro.core``) and the kernels sit
+    below the models, the engine and the sharding rules: none of their
+    modules imports those, and the BWMA ``Backend`` carries no serving
+    (paged) op; the paged-decode path belongs to ``repro.models.attention``.
+    """
+    above = ("repro.models", "repro.serve", "repro.distributed")
+    found = [
+        f"{path.relative_to(SRC)}: {mod}"
+        for pkg in ("core", "kernels")
+        for path in sorted((SRC / "repro" / pkg).glob("*.py"))
+        for mod in _imported_modules(path)
+        if mod.startswith(above)
+    ]
+    assert not found, found
+    assert not [m for m in dir(Backend) if "paged" in m]
 
 
 def test_pallas_matches_reference_ragged():

@@ -9,6 +9,7 @@ in the order the engine stores them (``kv_page_order``).  Attention parity
 is gated at 1e-6 (online-softmax reassociation — the PR-1 BWMA
 tolerance); the COW page copy must be bit-exact.
 """
+import dataclasses
 import functools
 
 import jax
@@ -16,16 +17,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.backend import resolve_backend
+import repro.configs as C
 from repro.kernels.paged_attention import (
-    KV_HEAD_AXIS,
     kv_page_order,
     mla_paged_attention_decode,
     paged_attention_decode,
     paged_copy,
 )
 from repro.models.attention import (
+    cow_copy_page,
     mla_paged_gather_attend,
+    paged_attend,
     paged_gather_attend,
 )
 
@@ -196,8 +198,9 @@ def test_paged_copy_survives_donating_jit():
 
 
 def test_backend_dispatch_parity():
-    """The Backend protocol surface: reference and pallas backends agree on
-    all three paged operators, including the dict-of-pools COW copy."""
+    """The decode path's one owner, ``repro.models.attention``: its
+    reference and pallas paths agree on GQA decode and on the
+    dict-of-pools COW copy."""
     rng = np.random.default_rng(11)
     B, H, dh = 2, 4, 16
     table, (k_pages, v_pages) = _table_and_pool(
@@ -205,18 +208,17 @@ def test_backend_dispatch_parity():
     )
     q = jnp.asarray(rng.standard_normal((B, 1, H, dh)), jnp.float32)
     seq_pos = jnp.asarray([5, 13], jnp.int32)
-    ref_be = resolve_backend("reference")
-    pal_be = resolve_backend("pallas")  # interpret auto-resolves off-TPU
-    a = ref_be.paged_attention_decode(q, k_pages, v_pages, table, seq_pos,
-                                      LAYER)
-    b = pal_be.paged_attention_decode(q, k_pages, v_pages, table, seq_pos,
-                                      LAYER)
+    cfg = C.get_config("minicpm-2b", smoke=True)
+    ref, pal = (dataclasses.replace(cfg, decode_backend=b)
+                for b in ("reference", "pallas"))  # interpreted off-TPU
+    a = paged_attend(ref, q, k_pages, v_pages, table, seq_pos, LAYER)
+    b = paged_attend(pal, q, k_pages, v_pages, table, seq_pos, LAYER)
     assert float(jnp.max(jnp.abs(a - b))) <= TOL
     # the layer-stacked pools themselves, page-id axis 1 — the adapters'
     # COW layout
     pools = {"k_pages": k_pages, "v_pages": v_pages}
-    got = pal_be.paged_copy_page(pools, 1, 2, head_axis=KV_HEAD_AXIS)
-    want = ref_be.paged_copy_page(pools, 1, 2, head_axis=KV_HEAD_AXIS)
+    got = cow_copy_page(pal, pools, 1, 2)
+    want = cow_copy_page(ref, pools, 1, 2)
     assert set(got) == set(want)
     for name in got:
         np.testing.assert_array_equal(np.asarray(got[name]),

@@ -461,7 +461,7 @@ def test_mid_prefill_preemption_and_resume():
     # while long is still several chunks from its first token.
     eng = Engine(cfg, params, EngineConfig(
         max_seqs=2, max_len=24, page_size=4, num_pages=9,
-        prefill_chunks_per_step=1,
+        prefill_tokens_per_step=4,
     ))
     a = eng.submit(short, max_new, rid=0)
     b = eng.submit(long, max_new, rid=1)
@@ -494,7 +494,7 @@ def test_long_prompt_admission_does_not_stall_decode():
     short = rng.integers(0, cfg.vocab_size, size=(8,)).astype(np.int32)
     long = rng.integers(0, cfg.vocab_size, size=(64,)).astype(np.int32)
     eng = Engine(cfg, params, EngineConfig(
-        max_seqs=2, max_len=80, page_size=8, prefill_chunks_per_step=1,
+        max_seqs=2, max_len=80, page_size=8, prefill_tokens_per_step=8,
     ))
     a = eng.submit(short, 24, rid=0, arrival_step=0)
     b = eng.submit(long, 4, rid=1, arrival_step=2)
@@ -804,8 +804,7 @@ def test_encdec_mid_prefill_preemption_and_resume():
 
 def test_prefill_token_budget_paces_admission():
     """prefill_tokens_per_step bounds the prompt tokens admitted per engine
-    step (page-granular); the deprecated chunk-count knob aliases to
-    chunks x chunk size."""
+    step, spent in whole chunks; 0 derives 4 chunks."""
     cfg = _paged_cfg(block=8)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(6)
@@ -824,9 +823,9 @@ def test_prefill_token_budget_paces_admission():
     assert eng.tokens_per_step == 8 and span == 3  # one chunk per step
     eng, span = admit_span(prefill_tokens_per_step=16)
     assert eng.tokens_per_step == 16 and span == 1  # two chunks per step
-    # deprecated alias: chunk count x chunk size
-    eng, span = admit_span(prefill_chunks_per_step=1)
-    assert eng.tokens_per_step == eng.chunk_size == 8 and span == 3
+    # a budget between chunk multiples rounds up to whole chunks
+    eng, span = admit_span(prefill_tokens_per_step=12)
+    assert eng.tokens_per_step == 12 and span == 1  # two chunks per step
     eng, span = admit_span()  # defaults: 4 chunks x 8 tokens
     assert eng.tokens_per_step == 32 and span == 0
 
@@ -1236,37 +1235,6 @@ def test_moe_unchunked_multi_page_engine_parity():
         eng.submit(pr, 6, rid=i)
     for r, b in zip(eng.run(), base):
         np.testing.assert_array_equal(np.asarray(r.out_tokens), b)
-
-
-def test_prefill_chunks_per_step_deprecation_warning(monkeypatch):
-    """The chunk-count admission alias warns exactly once per process and
-    only when explicitly set; the default (None) derives the same budget
-    silently."""
-    import warnings as _warnings
-
-    from repro.serve import engine as E
-
-    cfg = _paged_cfg(block=8)
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    monkeypatch.setattr(E, "_chunks_alias_warned", False)
-    with _warnings.catch_warnings(record=True) as rec:
-        _warnings.simplefilter("always")
-        eng = Engine(cfg, params, EngineConfig(
-            max_seqs=1, max_len=16, page_size=8,
-        ))
-        assert eng.tokens_per_step == 4 * eng.chunk_size  # alias default
-    assert not rec  # default config: no warning
-    with pytest.warns(DeprecationWarning, match="prefill_chunks_per_step"):
-        eng = Engine(cfg, params, EngineConfig(
-            max_seqs=1, max_len=16, page_size=8, prefill_chunks_per_step=2,
-        ))
-    assert eng.tokens_per_step == 2 * eng.chunk_size
-    with _warnings.catch_warnings(record=True) as rec:
-        _warnings.simplefilter("always")
-        Engine(cfg, params, EngineConfig(
-            max_seqs=1, max_len=16, page_size=8, prefill_chunks_per_step=2,
-        ))
-    assert not rec  # one-shot: second use stays silent
 
 
 # --------------------------------------------------------------------------

@@ -20,7 +20,6 @@ import pytest
 from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 import repro.configs as C
-from repro.core import backend as BE
 from repro.distributed import axes as AX
 from repro.distributed import sharding as SH
 from repro.kernels.bwma_gemm import bwma_gemm
@@ -29,6 +28,7 @@ from repro.kernels.paged_attention import (
     paged_attention_decode,
     paged_copy,
 )
+from repro.models import attention as attn
 from repro.models import model as M
 from repro.serve.engine import jitted_step_fns
 
@@ -174,7 +174,7 @@ def test_engine_step_keeps_pool_in_place(v5e_2x2, monkeypatch, cell, step):
     larger: no weight or pool crosses chips."""
     # the engine resolves "pallas" to interpret mode on this CPU host; a
     # step compiled for the chip needs the compiled kernel
-    monkeypatch.setattr(BE, "_interpret_default", lambda: False)
+    monkeypatch.setattr(attn, "kernels_interpreted", lambda: False)
     arch, cut, slots, num_pages, max_len, tp = CELLS[cell]
     cfg = C.get_config(arch, dtype=jnp.bfloat16, block=PAGE,
                        decode_backend="pallas", **cut)
